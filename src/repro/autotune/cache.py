@@ -9,12 +9,15 @@ back to the model.
 
 Keying (measure-once semantics):
 
-  graph key  — graph stats + mesh shape: ``n{n}_m{m}_r{R}x{C}x{fr}_``
-               ``t{nnz_tiles}_k{skew}`` where ``skew`` is the degree
-               skew ``max(deg)/mean(deg)`` rounded to one decimal (a
-               topology signature: RMAT vs uniform graphs land on
-               different keys, re-runs of the same graph on the same
-               mesh land on the same one).
+  graph key  — graph stats + mesh shape + device:
+               ``n{n}_m{m}_r{R}x{C}x{fr}_t{nnz_tiles}_k{skew}@{kind}``
+               where ``skew`` is the degree skew ``max(deg)/mean(deg)``
+               rounded to one decimal (a topology signature: RMAT vs
+               uniform graphs land on different keys, re-runs of the
+               same graph on the same mesh land on the same one) and
+               ``kind`` is the measuring device's ``device_kind`` — a
+               wall measured on the CPU (interpreted kernels) is never
+               served to a chip run, nor one chip's to another.
   config key — candidate config: ``{engine}|{overlap}|b{batch}|``
                ``t{bm}x{bk}`` (``t-`` for untiled engines).
 
@@ -73,10 +76,14 @@ def graph_key(
     nnz_tiles: int = 0,
     degree_skew: float = 1.0,
 ) -> str:
-    """Graph-stats + mesh-shape cache key (see module docstring)."""
+    """Graph-stats + mesh-shape + device cache key (see module
+    docstring); the device is the process's first."""
+    import jax
+
     return (
         f"n{int(n)}_m{int(m)}_r{int(R)}x{int(C)}x{int(fr)}"
         f"_t{int(nnz_tiles)}_k{float(degree_skew):.1f}"
+        f"@{jax.devices()[0].device_kind}"
     )
 
 

@@ -148,6 +148,9 @@ def default_bench(
             cand.overlap,
             tile=cand.tile,
             dense_cells=dense_cells,
+            mesh=mesh,
+            row_axis=row_axis,
+            col_axis=col_axis,
         )
 
         def run():

@@ -8,6 +8,8 @@ both the small-graph production path and the semantic reference for it.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax
@@ -55,8 +57,6 @@ def make_round_fn(
     operator_fn,
     n: int,
     num_levels: int | None = None,
-    fused_adjacency=None,
-    interpret: bool | None = None,
 ):
     """Build the jit-able per-round function.
 
@@ -64,9 +64,6 @@ def make_round_fn(
       operator_fn:     closure () -> TraversalOperator (captures adjacency).
       n:               vertex count (kept for signature stability).
       num_levels:      static level bound (dry-run) or None (early exit).
-      fused_adjacency: when given, run the fused Pallas kernel path on
-                       this dense adjacency instead of ``operator_fn``.
-      interpret:       Pallas interpret-mode override (CPU validation).
 
     The returned function maps
       (sources i32 [s], derived i32 [k, 3], omega f32 [n])
@@ -75,35 +72,43 @@ def make_round_fn(
     del n  # the operator knows its own row count
 
     def round_fn(sources, derived, omega):
-        if fused_adjacency is not None:
-            op = PallasDenseOperator(fused_adjacency, interpret=interpret)
-        else:
-            op = operator_fn()
-        return traversal_round(op, sources, derived, omega, num_levels=num_levels)
+        return traversal_round(
+            operator_fn(), sources, derived, omega, num_levels=num_levels
+        )
 
     return round_fn
 
 
 def _make_operator_fn(graph_residual, n, engine_kind):
-    """Operator factory + fused-path config for an engine kind."""
+    """Graph operands + operator factory for an engine kind.
+
+    Returns ``(operands, make_op)``: host-built device arrays and a
+    function ``make_op(*operands) -> TraversalOperator``.  The round
+    function takes the operands as jit arguments — a closed-over array
+    would be embedded in the program as a constant (gigabytes of HLO at
+    chip scale).
+    """
     if engine_kind == "dense":
         adjacency = jnp.asarray(graph_residual.dense_adjacency(np.float32))
-        return (lambda: engine.make_dense_operator(adjacency)), None, None
+        return (adjacency,), engine.make_dense_operator
     if engine_kind == "sparse":
         src_p, dst_p, _ = graph_residual.padded_arcs(multiple=8)
-        src_j, dst_j = jnp.asarray(src_p), jnp.asarray(dst_p)
-        return (lambda: engine.make_sparse_operator(src_j, dst_j, n)), None, None
+        return (
+            (jnp.asarray(src_p), jnp.asarray(dst_p)),
+            lambda src, dst: engine.make_sparse_operator(src, dst, n),
+        )
     if engine_kind in ("pallas", "pallas_bf16"):
-        from repro.kernels.ops import on_tpu
-
+        # 0/1 entries are exact in bf16: build the host copy in the
+        # engine's dtype so no f32 duplicate is shipped or converted
         dt = np.float32 if engine_kind == "pallas" else jnp.bfloat16
-        fused = jnp.asarray(graph_residual.dense_adjacency(np.float32), dt)
-        return None, fused, (not on_tpu())
+        return (jnp.asarray(graph_residual.dense_adjacency(dt)),), PallasDenseOperator
     raise ValueError(f"unknown engine {engine_kind!r}")
 
 
 def _make_weighted_operator_fn(graph_residual, n, engine_kind, delta):
-    """Weighted operator factory (bucketed traversal, all engine kinds).
+    """Weighted operands + operator factory (bucketed traversal, all
+    engine kinds; same ``(operands, make_op)`` contract as
+    :func:`_make_operator_fn`).
 
     "sparse" keeps the arc-list layout; "dense"/"pallas"/"pallas_bf16"
     share the dense float32 weight-matrix operator — the weighted bucket
@@ -114,11 +119,13 @@ def _make_weighted_operator_fn(graph_residual, n, engine_kind, delta):
     if engine_kind == "sparse":
         src_p, dst_p, _ = graph_residual.padded_arcs(multiple=8)
         w_p = graph_residual.padded_arc_weights(multiple=8)
-        src_j, dst_j, w_j = jnp.asarray(src_p), jnp.asarray(dst_p), jnp.asarray(w_p)
-        return lambda: WeightedSparseOperator(src_j, dst_j, w_j, n, delta)
+        return (
+            (jnp.asarray(src_p), jnp.asarray(dst_p), jnp.asarray(w_p)),
+            lambda src, dst, w: WeightedSparseOperator(src, dst, w, n, delta),
+        )
     if engine_kind in ("dense", "pallas", "pallas_bf16"):
         weights = jnp.asarray(graph_residual.dense_weights(np.float32))
-        return lambda: WeightedDenseOperator(weights, delta)
+        return (weights,), lambda w: WeightedDenseOperator(w, delta)
     raise ValueError(f"unknown engine {engine_kind!r}")
 
 
@@ -255,23 +262,17 @@ def betweenness_centrality(
     omega = jnp.asarray(omega_i, jnp.float32)
 
     if weighted:
-        operator_fn = _make_weighted_operator_fn(
+        operands, make_op = _make_weighted_operator_fn(
             residual, n, engine_kind, float(delta)
         )
-        fused_adjacency, interpret = None, None
     else:
-        operator_fn, fused_adjacency, interpret = _make_operator_fn(
-            residual, n, engine_kind
-        )
-    round_fn = make_round_fn(
-        operator_fn,
-        n,
-        num_levels=num_levels,
-        fused_adjacency=fused_adjacency,
-        interpret=interpret,
-    )
+        operands, make_op = _make_operator_fn(residual, n, engine_kind)
 
-    def block_fn(sources, derived):  # [1, s], [1, k, 3] -> block-dim outputs
+    def block_fn(operands, omega, sources, derived):
+        # [1, s], [1, k, 3] -> block-dim outputs
+        round_fn = make_round_fn(
+            lambda: make_op(*operands), n, num_levels=num_levels
+        )
         bc_r, ns, roots, levels = round_fn(sources[0], derived[0], omega)
         return bc_r, ns[None], roots[None], levels[None]
 
@@ -279,8 +280,8 @@ def betweenness_centrality(
         block_fn = jax.jit(block_fn)
 
     driver = BCDriver(
-        block_fn, schedule, n=n, prep=prep, ledger=ledger,
-        checkpoint=checkpoint, stop_rule=stop_rule,
+        functools.partial(block_fn, operands, omega), schedule, n=n,
+        prep=prep, ledger=ledger, checkpoint=checkpoint, stop_rule=stop_rule,
     )
     result = driver.run()
     return apply_sampling_rescale(result, plan)

@@ -22,6 +22,7 @@ from repro.graphs.graph import Graph
 __all__ = [
     "brandes_reference",
     "single_source_dependencies",
+    "single_source_dependencies_csr",
     "single_source_dependencies_weighted",
 ]
 
@@ -53,6 +54,54 @@ def single_source_dependencies(
         for v in adj[w]:
             if depth[v] == depth[w] - 1:
                 delta[v] += sigma[v] / sigma[w] * (1.0 + delta[w])
+    return delta, sigma, depth
+
+
+def single_source_dependencies_csr(
+    row_ptr: np.ndarray, col: np.ndarray, s: int, dtype=np.float64
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """:func:`single_source_dependencies` with numpy whole-level steps.
+
+    The same Brandes round on a CSR adjacency (:meth:`Graph.csr`), for
+    graphs with millions of arcs where the per-arc Python loop takes
+    seconds per source: a frontier BFS assigns depths, then σ and δ are
+    accumulated over the shortest-path DAG arcs one level at a time in
+    float64.  Returns (delta [n], sigma [n], depth [n]) like the loop
+    version, equal to it up to float64 summation order.
+    """
+    n = row_ptr.size - 1
+    depth = np.full(n, -1, dtype=np.int64)
+    depth[s] = 0
+    frontier = np.array([s], dtype=np.int64)
+    max_depth = 0
+    while True:
+        starts = row_ptr[frontier]
+        counts = row_ptr[frontier + 1] - starts
+        offs = np.repeat(starts - np.cumsum(counts) + counts, counts)
+        nbrs = col[offs + np.arange(offs.size)]
+        frontier = np.unique(nbrs[depth[nbrs] < 0]).astype(np.int64)
+        if frontier.size == 0:
+            break
+        max_depth += 1
+        depth[frontier] = max_depth
+    src = np.repeat(np.arange(n), np.diff(row_ptr))
+    lv, lw = depth[src], depth[col]
+    on_dag = (lv >= 0) & (lw == lv + 1)
+    order = np.argsort(lv[on_dag], kind="stable")
+    v, w = src[on_dag][order], col[on_dag][order].astype(np.int64)
+    bounds = np.searchsorted(lv[on_dag][order], np.arange(max_depth + 1))
+    sigma = np.zeros(n, dtype=dtype)
+    sigma[s] = 1.0
+    for d in range(max_depth):  # σ of level d+1 from its level-d parents
+        a, b = bounds[d], bounds[d + 1]
+        sigma += np.bincount(w[a:b], weights=sigma[v[a:b]], minlength=n)
+    delta = np.zeros(n, dtype=dtype)
+    for d in reversed(range(max_depth)):  # δ of level d from level d+1
+        a, b = bounds[d], bounds[d + 1]
+        vv, ww = v[a:b], w[a:b]
+        delta += np.bincount(
+            vv, weights=sigma[vv] / sigma[ww] * (1.0 + delta[ww]), minlength=n
+        )
     return delta, sigma, depth
 
 

@@ -63,7 +63,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.driver import (
     BCDriver,
     DEFAULT_MAX_RETRIES,
@@ -85,9 +84,9 @@ from repro.core.scheduler import Schedule, build_schedule
 from repro.graphs.graph import Graph
 from repro.graphs.partition import TwoDPartition, partition_2d
 from repro.roofline.model import (
-    V5E,
     auto_overlap_policy,
     cell_kernel_choice,
+    device_hardware,
     device_hbm_footprint,
     sparse_tile_bytes,
 )
@@ -181,7 +180,11 @@ def distributed_graph_arrays(
     dense_cells: np.ndarray | None = None,
     hybrid_threshold: float = 1.0,
     weights: np.ndarray | None = None,
-) -> tuple[jnp.ndarray, ...]:
+    *,
+    mesh: Mesh | None = None,
+    row_axis: str = "data",
+    col_axis: str = "model",
+) -> tuple[jax.Array, ...]:
     """Device arrays for the graph operands of a distributed round fn.
 
     The single source of the engine_kind × overlap → operand-layout
@@ -208,18 +211,34 @@ def distributed_graph_arrays(
     [R, C, max_arcs] arc-weight array; the dense engines carry f32
     weight blocks even under ``"pallas_bf16"`` (the σ/δ equality masks
     need exact distances, so weights never downcast).
+
+    With ``mesh``, every operand (leading dims [R, C]) is placed straight
+    from the host onto its ``(row_axis, col_axis)`` grid shard — each
+    device receives only its own cell, replicated over any other mesh
+    axis.  Without it the arrays land on the default device (small
+    graphs, tests); the round fn's jit then reshards them on every call.
     """
+    if mesh is None:
+        put = jnp.asarray
+    else:
+        from jax.sharding import NamedSharding
+
+        grid = NamedSharding(mesh, P(row_axis, col_axis))
+
+        def put(a):
+            return jax.device_put(np.asarray(a), grid)
+
     if engine_kind == "sparse":
         if weights is not None:
             return (
-                jnp.asarray(partition.src_local),
-                jnp.asarray(partition.dst_local),
-                jnp.asarray(partition.arc_weights(weights)),
+                put(partition.src_local),
+                put(partition.dst_local),
+                put(partition.arc_weights(weights)),
             )
         if normalize_overlap(overlap) != "none":
             ring_src, ring_dst = partition.ring_arcs()
-            return (jnp.asarray(ring_src), jnp.asarray(ring_dst))
-        return (jnp.asarray(partition.src_local), jnp.asarray(partition.dst_local))
+            return (put(ring_src), put(ring_dst))
+        return (put(partition.src_local), put(partition.dst_local))
     if engine_kind in ("pallas_sparse", "pallas_hybrid"):
         ring = weights is None and normalize_overlap(overlap) != "none"
         bm, bk = tile if tile is not None else (None, None)
@@ -235,26 +254,28 @@ def distributed_graph_arrays(
                 bm, bk, dense_cells=dense_cells, ring=ring, weights=weights
             )
             layout = hybrid.sparse
-            lead = (jnp.asarray(hybrid.blocks),)
+            lead = (put(hybrid.blocks),)
         if ring:
             tiles = (
-                jnp.asarray(layout.ring_tiles),
-                jnp.asarray(layout.ring_tile_rows),
-                jnp.asarray(layout.ring_tile_cols),
+                put(layout.ring_tiles),
+                put(layout.ring_tile_rows),
+                put(layout.ring_tile_cols),
             )
         else:
             tiles = (
-                jnp.asarray(layout.tiles),
-                jnp.asarray(layout.tile_rows),
-                jnp.asarray(layout.tile_cols),
+                put(layout.tiles),
+                put(layout.tile_rows),
+                put(layout.tile_cols),
             )
         if engine_kind == "pallas_hybrid":
-            return lead + tiles + (jnp.asarray(dense_cells.astype(np.int32)),)
+            return lead + tiles + (put(dense_cells.astype(np.int32)),)
         return tiles
     if weights is not None:
-        return (jnp.asarray(partition.dense_blocks(np.float32, weights=weights)),)
-    dt = jnp.bfloat16 if engine_kind == "pallas_bf16" else jnp.float32
-    return (jnp.asarray(partition.dense_blocks(np.float32), dt),)
+        return (put(partition.dense_blocks(np.float32, weights=weights)),)
+    # 0/1 entries are exact in bf16: build the host blocks in the engine's
+    # dtype so no f32 copy is shipped or converted
+    dt = jnp.bfloat16 if engine_kind == "pallas_bf16" else np.float32
+    return (put(partition.dense_blocks(dt)),)
 
 
 def estimate_device_footprint(
@@ -397,7 +418,7 @@ def level_time_estimates(
     bk: int | None = None,
     tile_counts: dict | None = None,
     dense_cells: np.ndarray | None = None,
-    hw=V5E,
+    hw=None,
 ) -> tuple[float, float, float]:
     """Roofline prices of one traversal level: (compute, expand, fold) s.
 
@@ -409,9 +430,13 @@ def level_time_estimates(
     cell — each cell streams its *chosen* representation
     (``dense_cells``, default: the roofline choice), and the level waits
     for the slowest cell, so the compute term is the per-cell maximum.
+    ``hw`` defaults to the peaks of the device the run is on
+    (:func:`repro.roofline.model.device_hardware`).
     """
     R, C, chunk, s = partition.R, partition.C, partition.chunk, batch_size
     from repro.roofline.model import adjacency_stream_bytes
+
+    hw = device_hardware() if hw is None else hw
 
     if engine_kind in ("pallas", "pallas_bf16"):
         flops = 2.0 * (C * chunk) * (R * chunk) * s
@@ -475,7 +500,7 @@ def prior_round_seconds(
     bk: int | None = None,
     tile_counts: dict | None = None,
     dense_cells: np.ndarray | None = None,
-    hw=V5E,
+    hw=None,
     measured_level_s: float | None = None,
     prior_levels: int | None = None,
 ) -> float:
@@ -499,6 +524,7 @@ def prior_round_seconds(
     levels = PRIOR_LEVELS if prior_levels is None else int(prior_levels)
     if measured_level_s is not None:
         return float(measured_level_s) * levels
+    hw = device_hardware() if hw is None else hw
     compute_s, expand_s, fold_s = level_time_estimates(
         partition, engine_kind, batch_size,
         bm=bm, bk=bk, tile_counts=tile_counts, dense_cells=dense_cells, hw=hw,
@@ -533,7 +559,7 @@ def resolve_overlap(
     bk: int | None = None,
     tile_counts: dict | None = None,
     dense_cells: np.ndarray | None = None,
-    hw=V5E,
+    hw=None,
     measured: dict | None = None,
 ) -> str:
     """Resolve ``overlap="auto"`` from measured or roofline level costs.
@@ -549,10 +575,12 @@ def resolve_overlap(
     tile shape the engine will actually be built with (defaults to the
     partition default), so the estimate prices the real layout;
     ``dense_cells``: the hybrid engine's resolved per-cell choice, for
-    the same reason.
+    the same reason.  ``hw`` defaults to the peaks of the device the run
+    is on (:func:`repro.roofline.model.device_hardware`).
     """
     if overlap != "auto":
         return normalize_overlap(overlap)
+    hw = device_hardware() if hw is None else hw
     compute_s, expand_s, fold_s = level_time_estimates(
         partition, engine_kind, batch_size,
         bm=bm, bk=bk, tile_counts=tile_counts, dense_cells=dense_cells, hw=hw,
@@ -606,7 +634,7 @@ def one_degree_reduce_distributed(
         )
         return omega[:n], removed
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axes), P(axes)),
@@ -771,10 +799,10 @@ def make_distributed_round_fn(
                 "split backward payload is an unweighted sparse-engine "
                 "benchmark mode"
             )
-    if use_pallas and interpret is None:
-        from repro.kernels.ops import on_tpu
+    if use_pallas:
+        from repro.kernels.ops import resolve_interpret
 
-        interpret = not on_tpu()
+        interpret = resolve_interpret(interpret)
     chunk = partition.chunk
     # Ring hops are mesh-wide collective-permutes: sub-cluster replicas
     # must stay in level-loop lockstep or the rendezvous deadlocks (the
@@ -1016,7 +1044,7 @@ def make_distributed_round_fn(
     )
     if integrity != "off":
         out_specs = out_specs + (P(*rep, None),)
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
     return jax.jit(shmapped)
@@ -1324,15 +1352,20 @@ def distributed_betweenness_centrality(
         delta=delta,
     )
 
+    from jax.sharding import NamedSharding
+
     omega_pad = np.zeros(part.n_pad, np.float32)
     omega_pad[: graph.n] = omega_i
     # reorder omega into chunk-owner layout: flat position = chunk-id*chunk + off
     # chunk ids are contiguous in vertex order, so identity layout works.
-    omega_dev = jnp.asarray(omega_pad)
+    omega_dev = jax.device_put(
+        omega_pad, NamedSharding(mesh, P((col_axis, row_axis)))
+    )
 
     graph_args = distributed_graph_arrays(
         part, engine_kind, overlap, tile=tile, dense_cells=dense_cells,
         weights=residual.w if weighted else None,
+        mesh=mesh, row_axis=row_axis, col_axis=col_axis,
     )
 
     def block_fn(sources, derived):

@@ -88,6 +88,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import F32_EXACT, matmul_f32
+
 __all__ = [
     "TraversalOperator",
     "DenseOperator",
@@ -325,7 +327,7 @@ class DenseOperator(TraversalOperator):
         self.n_rows = adjacency.shape[0]
 
     def apply(self, x):
-        return self.adjacency.astype(jnp.float32) @ x
+        return matmul_f32(self.adjacency, x)
 
 
 class SparseOperator(TraversalOperator):
@@ -364,7 +366,7 @@ class PallasDenseOperator(TraversalOperator):
         self.interpret = interpret
 
     def apply(self, x):  # reference semantics, used by parity tests
-        return self.adjacency.astype(jnp.float32) @ x
+        return matmul_f32(self.adjacency, x)
 
     def forward_level(self, lvl, sigma, depth):
         from repro.kernels import ops as kops
@@ -685,7 +687,7 @@ class DistributedPallasOperator(DistributedOperator):
         self.interpret = interpret
 
     def _local(self, x_col):
-        return self.adjacency_block.astype(jnp.float32) @ x_col
+        return matmul_f32(self.adjacency_block, x_col)
 
     # ------------------------------------------------------ block hooks
     # The dense and blocked-sparse fused operators share the entire level
@@ -749,7 +751,7 @@ class DistributedPallasOperator(DistributedOperator):
     def _ring_partial(self, x_owned):
         # dense-block counterpart of the arc-list ring (used via apply)
         return self._ring_steps(
-            (x_owned,), lambda a_r, hand, acc: acc + a_r.astype(jnp.float32) @ hand[0]
+            (x_owned,), lambda a_r, hand, acc: acc + matmul_f32(a_r, hand[0])
         )
 
     def forward_level(self, lvl, sigma, depth):
@@ -963,12 +965,15 @@ class DistributedPallasSparseOperator(DistributedPallasOperator):
 
     def _local(self, x_col):
         # parity/debug path only — the engine runs the fused level hooks
-        return self._dense_of(self._full_block(), x_col.shape[0]) @ x_col
+        return matmul_f32(
+            self._dense_of(self._full_block(), x_col.shape[0]), x_col
+        )
 
     def _ring_partial(self, x_owned):
         return self._ring_steps(
             (x_owned,),
-            lambda blk, hand, acc: acc + self._dense_of(blk, self.chunk) @ hand[0],
+            lambda blk, hand, acc: acc
+            + matmul_f32(self._dense_of(blk, self.chunk), hand[0]),
         )
 
 
@@ -1052,12 +1057,15 @@ class DistributedPallasHybridOperator(DistributedPallasSparseOperator):
 
     def _local(self, x_col):
         # parity/debug path only — the engine runs the fused level hooks
-        return self._mixed_dense(self._full_block(), x_col.shape[0]) @ x_col
+        return matmul_f32(
+            self._mixed_dense(self._full_block(), x_col.shape[0]), x_col
+        )
 
     def _ring_partial(self, x_owned):
         return self._ring_steps(
             (x_owned,),
-            lambda blk, hand, acc: acc + self._mixed_dense(blk, self.chunk) @ hand[0],
+            lambda blk, hand, acc: acc
+            + matmul_f32(self._mixed_dense(blk, self.chunk), hand[0]),
         )
 
 
@@ -1173,7 +1181,7 @@ class WeightedDenseOperator(WeightedTraversalOperator):
 
     def apply(self, x):
         # unweighted reachability semantics (parity/debug only)
-        return self.mask.astype(jnp.float32) @ x
+        return matmul_f32(self.mask, x)
 
     def relax(self, dist, frontier, heavy):
         wsel = self.w_heavy if heavy else self.w_light
@@ -1190,11 +1198,11 @@ class WeightedDenseOperator(WeightedTraversalOperator):
         # dot_general over u (same contraction the unweighted matmul uses,
         # so unit weights at delta=1 reproduce DenseOperator bitwise)
         eq = self._eq(dist).astype(jnp.float32)
-        return jnp.einsum("uvs,us->vs", eq, sigma_in)
+        return jnp.einsum("uvs,us->vs", eq, sigma_in, precision=F32_EXACT)
 
     def delta_step(self, g, dist):
         eq = self._eq(dist).astype(jnp.float32)
-        return jnp.einsum("uvs,vs->us", eq, g)
+        return jnp.einsum("uvs,vs->us", eq, g, precision=F32_EXACT)
 
 
 class WeightedSparseOperator(WeightedTraversalOperator):
@@ -1429,7 +1437,7 @@ class DistributedWeightedDenseOperator(DistributedOperator):
 
     def _local(self, x_col):
         # unweighted reachability semantics (parity/debug only)
-        return self.mask.astype(jnp.float32) @ x_col
+        return matmul_f32(self.mask, x_col)
 
     def relax(self, dist, frontier, heavy):
         wsel = self.w_heavy if heavy else self.w_light

@@ -39,6 +39,7 @@ __all__ = [
     "ReplicaLostError",
     "IntegrityError",
     "is_transient_error",
+    "TRANSIENT_STATUSES",
 ]
 
 
@@ -60,8 +61,8 @@ class TransientRoundError(RuntimeError):
     Raised by the chaos harness (:mod:`repro.distributed.chaos`) to model
     the transient XLA/runtime failures a long-lived service sees; the
     driver's per-block retry loop (:class:`repro.core.driver.BCDriver`)
-    treats it — and runtime error types named in
-    :data:`TRANSIENT_ERROR_NAMES` — as retryable within the retry budget.
+    treats it — and a runtime error whose status is in
+    :data:`TRANSIENT_STATUSES` — as retryable within the retry budget.
     Any other exception propagates immediately.
     """
 
@@ -81,21 +82,26 @@ class ReplicaLostError(RuntimeError):
         self.replica = int(replica)
 
 
-#: Exception type *names* treated as transient alongside
-#: :class:`TransientRoundError` — matched by name so the check never
-#: imports backend-private modules.  XLA surfaces preemption/rendezvous
-#: hiccups as these; a retry budget bounds the damage when one is
-#: actually permanent.
-TRANSIENT_ERROR_NAMES = ("XlaRuntimeError", "UnavailableError", "InternalError")
+#: Runtime status codes retried in place alongside
+#: :class:`TransientRoundError`.  The runtime raises every device, compile
+#: and allocation failure as one :class:`jax.errors.JaxRuntimeError`
+#: whose message starts with its status; only UNAVAILABLE (a peer or
+#: link that may come back) is transient.  RESOURCE_EXHAUSTED, INTERNAL,
+#: INVALID_ARGUMENT and the rest fail the same way on every retry, so
+#: retrying them would only hide a device fault behind a slower run.
+TRANSIENT_STATUSES = ("UNAVAILABLE",)
 
 
 def is_transient_error(exc: BaseException) -> bool:
     """True when a round failure should be retried in place."""
+    import jax
+
     if isinstance(exc, TransientRoundError):
         return True
-    if isinstance(exc, ReplicaLostError):
+    if not isinstance(exc, jax.errors.JaxRuntimeError):
         return False
-    return type(exc).__name__ in TRANSIENT_ERROR_NAMES
+    status = str(exc).split(":", 1)[0].strip()
+    return status in TRANSIENT_STATUSES
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,7 +2,7 @@
 
 Each kernel ships three layers:
   <name>.py — ``pl.pallas_call`` + explicit BlockSpec VMEM tiling
-  ops.py    — jit'd wrapper (padding, dispatch, CPU interpret fallback)
+  ops.py    — jit'd wrapper (padding, dispatch; interpreted on the CPU backend)
   ref.py    — pure-jnp oracle (the semantics; tests assert allclose)
 """
 from repro.kernels.ops import (

@@ -45,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import F32_EXACT
+
 __all__ = [
     "make_sparse_kernel",
     "frontier_sparse_kernel",
@@ -130,7 +132,10 @@ def make_sparse_kernel(operand_fn, *, carried: bool):
 
         rhs = operand_fn(lvl_ref[0], *operand_refs)
         acc_ref[...] += jnp.dot(
-            a_ref[0].astype(jnp.float32), rhs, preferred_element_type=jnp.float32
+            a_ref[0].astype(jnp.float32),
+            rhs,
+            preferred_element_type=jnp.float32,
+            precision=F32_EXACT,
         )
 
         @pl.when(last)

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import F32_EXACT
+
 __all__ = [
     "dependency_spmm_kernel",
     "dependency_spmm_pallas",
@@ -63,7 +65,10 @@ def dependency_spmm_kernel(
         0.0,
     )
     acc_ref[...] += jnp.dot(
-        a_ref[...].astype(jnp.float32), g, preferred_element_type=jnp.float32
+        a_ref[...].astype(jnp.float32),
+        g,
+        preferred_element_type=jnp.float32,
+        precision=F32_EXACT,
     )
 
     @pl.when(k == k_steps - 1)
@@ -163,7 +168,10 @@ def dependency_partial_kernel(
         0.0,
     )
     acc_ref[...] += jnp.dot(
-        a_ref[...].astype(jnp.float32), g, preferred_element_type=jnp.float32
+        a_ref[...].astype(jnp.float32),
+        g,
+        preferred_element_type=jnp.float32,
+        precision=F32_EXACT,
     )
 
     @pl.when(k == k_steps - 1)
@@ -199,7 +207,10 @@ def dependency_partial_acc_kernel(
         0.0,
     )
     acc_ref[...] += jnp.dot(
-        a_ref[...].astype(jnp.float32), g, preferred_element_type=jnp.float32
+        a_ref[...].astype(jnp.float32),
+        g,
+        preferred_element_type=jnp.float32,
+        precision=F32_EXACT,
     )
 
     @pl.when(k == k_steps - 1)

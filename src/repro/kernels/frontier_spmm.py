@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import F32_EXACT
+
 __all__ = [
     "frontier_spmm_kernel",
     "frontier_spmm_pallas",
@@ -62,6 +64,7 @@ def frontier_spmm_kernel(
         a_ref[...].astype(jnp.float32),
         frontier,
         preferred_element_type=jnp.float32,
+        precision=F32_EXACT,
     )
 
     @pl.when(k == k_steps - 1)
@@ -168,6 +171,7 @@ def frontier_partial_kernel(
         a_ref[...].astype(jnp.float32),
         frontier,
         preferred_element_type=jnp.float32,
+        precision=F32_EXACT,
     )
 
     @pl.when(k == k_steps - 1)
@@ -198,6 +202,7 @@ def frontier_partial_acc_kernel(
         a_ref[...].astype(jnp.float32),
         frontier,
         preferred_element_type=jnp.float32,
+        precision=F32_EXACT,
     )
 
     @pl.when(k == k_steps - 1)

@@ -2,8 +2,9 @@
 
 Each op:
   * pads inputs to block multiples (MXU lanes: multiples of (8, 128)),
-  * dispatches to the Pallas kernel (interpret mode on CPU — the
-    container validates kernel semantics; TPU executes them compiled),
+  * dispatches to the Pallas kernel: compiled on TPU, interpreted on the
+    CPU backend (where tests validate kernel semantics), and refused on
+    any other backend (:func:`resolve_interpret`),
   * falls back to the pure-jnp reference when ``use_pallas=False``
     (XLA path; useful for A/B perf comparison and as the grad path).
 
@@ -42,12 +43,30 @@ __all__ = [
     "checksum_append",
     "checksum_residual",
     "bucket_index",
-    "on_tpu",
+    "resolve_interpret",
 ]
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Pallas interpret mode for the default backend, unless given.
+
+    TPU runs the kernels compiled and the CPU backend interprets them.
+    Any other backend raises instead of interpreting in silence: the
+    kernels are Mosaic TPU programs, and an interpreted kernel on an
+    accelerator would report the interpreter's speed as the device's.
+    """
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels are TPU programs; backend {backend!r} can "
+        "neither compile them nor is it the CPU backend that interprets "
+        "them (pass interpret=True explicitly to interpret anyway)"
+    )
 
 
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int, fill=0):
@@ -151,8 +170,7 @@ def frontier_spmm(
     """Fused forward BFS level. See kernels/frontier_spmm.py."""
     if not use_pallas:
         return ref.frontier_spmm_ref(adjacency, sigma, depth, lvl)
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     n, s = sigma.shape
     bm, bk, bs, npad = _square_geometry(n, s, bm, bk, bs)
     a = jnp.pad(adjacency, ((0, npad - n), (0, npad - n))) if npad != n else adjacency
@@ -182,8 +200,7 @@ def dependency_spmm(
     """Fused backward dependency level. See kernels/dependency_spmm.py."""
     if not use_pallas:
         return ref.dependency_spmm_ref(adjacency, sigma, depth, delta, omega, lvl)
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     n, s = sigma.shape
     bm, bk, bs, npad = _square_geometry(n, s, bm, bk, bs)
     a = jnp.pad(adjacency, ((0, npad - n), (0, npad - n))) if npad != n else adjacency
@@ -227,8 +244,7 @@ def frontier_spmm_partial(
     if not use_pallas:
         t = ref.frontier_partial_ref(adjacency, sigma, depth, lvl)
         return t if acc is None else acc + t
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     m, kdim = adjacency.shape
     _, s = sigma.shape
     bm, bk, bs = _rect_geometry(m, kdim, s, bm, bk, bs)
@@ -269,8 +285,7 @@ def dependency_spmm_partial(
     if not use_pallas:
         t = ref.dependency_partial_ref(adjacency, sigma, depth, delta, omega, lvl)
         return t if acc is None else acc + t
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     m, kdim = adjacency.shape
     _, s = sigma.shape
     bm, bk, bs = _rect_geometry(m, kdim, s, bm, bk, bs)
@@ -321,8 +336,7 @@ def frontier_spmm_sparse(
         a = tiles_to_dense(tiles, tile_rows, tile_cols, m, sigma.shape[0])
         t = ref.frontier_partial_ref(a, sigma, depth, lvl)
         return t if acc is None else acc + t
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     s = sigma.shape[1]
     bs = _pick_block(s, bs, 128)
     sg, dp, ac = _pad_cols(bs, (sigma, 0), (depth, -1), (acc, 0))
@@ -361,8 +375,7 @@ def dependency_spmm_sparse(
         a = tiles_to_dense(tiles, tile_rows, tile_cols, m, sigma.shape[0])
         t = ref.dependency_partial_ref(a, sigma, depth, delta, omega, lvl)
         return t if acc is None else acc + t
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     s = sigma.shape[1]
     bs = _pick_block(s, bs, 128)
     sg, dp, dl, ac = _pad_cols(bs, (sigma, 0), (depth, -1), (delta, 0), (acc, 0))
@@ -386,8 +399,7 @@ def segment_bag(
     """EmbeddingBag(sum). See kernels/segment_bag.py."""
     if not use_pallas:
         return ref.segment_bag_ref(table, indices, weights)
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     V, D = table.shape
     bd = _pick_block(D, bd, 128)
     t = _pad_to(table, 1, bd)

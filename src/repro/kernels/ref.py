@@ -5,15 +5,30 @@ every shape/dtype combination the tests sweep.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
+    "F32_EXACT",
+    "matmul_f32",
     "frontier_spmm_ref",
     "dependency_spmm_ref",
     "frontier_partial_ref",
     "dependency_partial_ref",
     "segment_bag_ref",
 ]
+
+#: Precision of every traversal contraction ``A @ x``, XLA and in-kernel
+#: alike.  The 0/1 adjacency is exact in bf16, but the right operand is
+#: not: path counts σ and g = (1 + δ + ω) / σ need all 24 bits of f32.
+#: On a v5e, default precision rounds them to bf16 in XLA dots and in the
+#: BCSR kernel (relative errors of 1e-3), breaking the oracle parity.
+F32_EXACT = jax.lax.Precision.HIGHEST
+
+
+def matmul_f32(a, x):
+    """``a @ x`` in f32 at :data:`F32_EXACT` (``a`` cast to f32 first)."""
+    return jnp.matmul(a.astype(jnp.float32), x, precision=F32_EXACT)
 
 
 def frontier_spmm_ref(adjacency, sigma, depth, lvl):
@@ -28,7 +43,7 @@ def frontier_spmm_ref(adjacency, sigma, depth, lvl):
     Returns (sigma_out, depth_out).
     """
     frontier = sigma * (depth == lvl - 1)
-    contrib = adjacency.astype(jnp.float32) @ frontier
+    contrib = matmul_f32(adjacency, frontier)
     newly = (contrib > 0) & (depth < 0)
     depth_out = jnp.where(newly, lvl, depth)
     sigma_out = sigma + jnp.where(newly, contrib, 0.0)
@@ -52,7 +67,7 @@ def dependency_spmm_ref(adjacency, sigma, depth, delta, omega, lvl):
     g = jnp.where(
         depth == lvl + 1, (1.0 + delta + omega[:, None]) / safe_sigma, 0.0
     )
-    t = adjacency.astype(jnp.float32) @ g
+    t = matmul_f32(adjacency, g)
     return delta + jnp.where(depth == lvl, sigma * t, 0.0)
 
 
@@ -69,7 +84,7 @@ def frontier_partial_ref(adjacency, sigma, depth, lvl):
     happens after the cross-device fold (operators.DistributedPallasOperator).
     """
     frontier = sigma * (depth == lvl - 1)
-    return adjacency.astype(jnp.float32) @ frontier
+    return matmul_f32(adjacency, frontier)
 
 
 def dependency_partial_ref(adjacency, sigma, depth, delta, omega, lvl):
@@ -89,7 +104,7 @@ def dependency_partial_ref(adjacency, sigma, depth, delta, omega, lvl):
     g = jnp.where(
         depth == lvl + 1, (1.0 + delta + omega[:, None]) / safe_sigma, 0.0
     )
-    return adjacency.astype(jnp.float32) @ g
+    return matmul_f32(adjacency, g)
 
 
 def segment_bag_ref(table, indices, weights=None):

@@ -110,6 +110,7 @@ from repro.core.distributed import (
 from repro.distributed.fault_tolerance import BCCheckpoint
 from repro.graphs import grid_graph, rmat_graph, road_like_graph
 from repro.graphs.generators import WEIGHT_MODES, weighted_copy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import SAMPLING_MODES
 
 
@@ -321,6 +322,7 @@ def main() -> None:
     ap.add_argument("--top", type=int, default=10)
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO, format="%(name)s: %(message)s")
+    enable_compile_cache()
 
     if args.rmat_scale is not None:
         graph = rmat_graph(

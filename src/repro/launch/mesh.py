@@ -9,15 +9,30 @@ Axis roles:
   "data"  — batch / MGBC grid rows (R)
   "model" — tensor/expert parallel / MGBC grid columns (C)
 
-``make_mesh`` is the version-compat constructor (JAX 0.4.37 lacks
-``jax.sharding.AxisType``); every mesh in tests, benchmarks, examples
-and launchers goes through it.
+Every mesh in tests, benchmarks, examples and launchers goes through
+:func:`make_mesh`, which states the axis types explicitly: all axes are
+``Auto`` (the shard_map bodies manage their own collectives).
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh
+from typing import Sequence
+
+import jax
 
 __all__ = ["make_mesh", "make_production_mesh", "make_bench_mesh"]
+
+
+def make_mesh(
+    shape: Sequence[int], axis_names: Sequence[str], *, devices=None
+) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    axis_names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(shape),
+        axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -41,6 +41,7 @@ from repro.core import betweenness_centrality
 from repro.core.distributed import distributed_betweenness_centrality
 from repro.distributed.fault_tolerance import BCCheckpoint
 from repro.graphs import grid_graph, rmat_graph, road_like_graph
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (
     BCSnapshotStore,
     BlockBudgetStop,
@@ -179,7 +180,9 @@ def run_serving(
                         "roots_accumulated": result.roots_accumulated,
                         "stopped_early": result.stopped_early,
                         "wall_s": time.perf_counter() - t0,
+                        "driver_wall_s": result.wall_s,
                         "sampling": result.sampling_stats,
+                        "recovery": result.recovery_stats,
                     }
                 )
                 if not result.stopped_early:
@@ -259,6 +262,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None, help="shared refresher state")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO, format="%(name)s: %(message)s")
+    enable_compile_cache()
 
     if args.rmat_scale is not None:
         graph = rmat_graph(args.rmat_scale, args.edge_factor, seed=1)

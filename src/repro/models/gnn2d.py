@@ -236,9 +236,7 @@ def make_gnn2d_loss_fn(
         batch_specs_in["labels"] = owner
         batch_specs_in["label_mask"] = owner
 
-    from repro.compat import shard_map
-
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), batch_specs_in),  # params replicated

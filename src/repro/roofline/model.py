@@ -30,6 +30,9 @@ import numpy as np
 
 __all__ = [
     "V5E",
+    "HARDWARE",
+    "HardwareSpec",
+    "device_hardware",
     "RooflineTerms",
     "roofline_terms",
     "link_bytes",
@@ -62,6 +65,35 @@ V5E = HardwareSpec(
     hbm_bandwidth=819e9,
     ici_link_bandwidth=50e9,
 )
+
+#: Peak rates by ``jax.Device.device_kind`` — the one table every roofline
+#: price reads.  v5e: 197 TFLOP/s bf16 and 819 GB/s HBM per chip (Google
+#: Cloud documentation, "TPU v5e").
+HARDWARE: dict[str, HardwareSpec] = {"TPU v5 lite": V5E}
+
+
+def device_hardware(device=None) -> HardwareSpec:
+    """The :class:`HardwareSpec` of ``device`` (default: the first device).
+
+    A TPU whose ``device_kind`` is not in :data:`HARDWARE` raises: a
+    roofline priced with another chip's peaks is wrong, not approximate.
+    The CPU backend has no peaks of its own worth pricing with; it plans
+    as a v5e chip, so CPU rehearsals make the same schedule choices the
+    chip run would.  Any other platform raises.
+    """
+    import jax
+
+    device = jax.devices()[0] if device is None else device
+    if device.platform == "cpu":
+        return V5E
+    spec = HARDWARE.get(device.device_kind)
+    if spec is None:
+        raise ValueError(
+            f"no peak-rate entry for device kind {device.device_kind!r} "
+            f"(platform {device.platform!r}); add it to "
+            f"repro.roofline.model.HARDWARE with its published source"
+        )
+    return spec
 
 
 @dataclasses.dataclass

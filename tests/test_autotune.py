@@ -23,6 +23,7 @@ Five layers of checks:
 """
 import json
 import logging
+import types
 
 import numpy as np
 import pytest
@@ -123,10 +124,20 @@ def test_cache_tolerates_corrupt_and_foreign_files(tmp_path):
     assert mem.num_records() == 1 and mem.stats()["path"] is None
 
 
-def test_key_schemas():
+def test_key_schemas(monkeypatch):
     assert graph_key(32, 100, R=2, C=4, fr=2, nnz_tiles=7, degree_skew=3.14) == (
-        "n32_m100_r2x4x2_t7_k3.1"
+        "n32_m100_r2x4x2_t7_k3.1@cpu"
     )
+    # the key names the measuring device: a wall measured on the CPU
+    # (interpreted kernels) is never served to a chip run
+    cpu_key = graph_key(32, 100, R=2, C=4)
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+    assert graph_key(32, 100, R=2, C=4) == "n32_m100_r2x4x1_t0_k1.0@TPU v5 lite"
+    cache = CostCache(None)
+    cache.put(cpu_key, "sparse|none|b4|t-", CostRecord(level_s=1.0))
+    assert cache.get(graph_key(32, 100, R=2, C=4), "sparse|none|b4|t-") is None
+    monkeypatch.undo()
     assert config_key("pallas_sparse", "expand", 16, (8, 8)) == (
         "pallas_sparse|expand|b16|t8x8"
     )

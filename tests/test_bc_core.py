@@ -210,3 +210,30 @@ def test_h3_composition_effect_on_suburb_topology():
     np.testing.assert_allclose(h3.bc, ref, rtol=1e-5, atol=1e-5)
     assert h3.schedule.num_derived > h2.schedule.num_derived
     assert h3.forward_columns < h2.forward_columns
+
+
+@pytest.mark.parametrize(
+    "graph",
+    [
+        rmat_graph(8, 8, seed=1),
+        disjoint_union(gnp_graph(30, 0.1, seed=4), path_graph(9)),
+        road_like_graph(6, 7, seed=2),
+    ],
+    ids=["rmat", "two-components", "road"],
+)
+def test_csr_oracle_matches_loop_oracle(graph):
+    """The whole-level numpy Brandes round (chip-scale oracle) equals the
+    per-arc loop round from every source, in float64."""
+    from repro.core.brandes_ref import (
+        single_source_dependencies,
+        single_source_dependencies_csr,
+    )
+
+    adj = graph.adjacency_lists()
+    row_ptr, col = graph.csr()
+    for s in range(graph.n):
+        want = single_source_dependencies(adj, graph.n, s)
+        got = single_source_dependencies_csr(row_ptr, col, s)
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-12, atol=1e-12)
+        np.testing.assert_allclose(got[1], want[1], rtol=1e-12)
+        np.testing.assert_array_equal(got[2], want[2])

@@ -49,6 +49,7 @@ from repro.distributed.fault_tolerance import (
     ReplicaLostError,
     StragglerPolicy,
     TransientRoundError,
+    is_transient_error,
     schedule_fingerprint,
 )
 from repro.graphs import disjoint_union, gnp_graph, path_graph, skewed_depth_graph
@@ -195,6 +196,24 @@ def test_transient_budget_exhausted_raises(case):
     with pytest.raises(TransientRoundError):
         drv.run()
     assert drv.recovery["retries"] == 1
+
+
+@pytest.mark.parametrize(
+    "exc,transient",
+    [
+        (TransientRoundError("injected"), True),
+        (jax.errors.JaxRuntimeError("UNAVAILABLE: peer went away"), True),
+        (jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of HBM"), False),
+        (jax.errors.JaxRuntimeError("INTERNAL: Mosaic failed to compile"), False),
+        (ReplicaLostError(1), False),
+        (RuntimeError("UNAVAILABLE: not raised by the runtime"), False),
+    ],
+    ids=["chaos", "unavailable", "oom", "compile", "replica-lost", "foreign"],
+)
+def test_only_unavailable_runtime_errors_are_transient(exc, transient):
+    """Retry only what can succeed on retry: an OOM or a compile error
+    fails identically every time, and retrying it would hide the fault."""
+    assert is_transient_error(exc) is transient
 
 
 def test_poison_block_quarantined_and_recovered(case):

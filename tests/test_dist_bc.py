@@ -155,3 +155,38 @@ def test_distributed_one_degree_matches_host():
 def test_tree_contraction_distributed(heuristics):
     g = road_like_graph(4, 4, spur_fraction=1.0, seed=6)
     _check(g, (2, 4), heuristics)
+
+
+@pytest.mark.parametrize("engine_kind", ["sparse", "pallas", "pallas_sparse"])
+def test_one_by_one_mesh(engine_kind):
+    """A 1x1 mesh — one chip driven through the distributed code path —
+    is a valid 2-D decomposition: R = C = 1, collectives over one device."""
+    g = rmat_graph(6, 8, seed=1)
+    mesh = _mesh((1, 1), ("data", "model"))
+    bc, _ = distributed_betweenness_centrality(
+        g, mesh, heuristics="h0", batch_size=16, engine_kind=engine_kind
+    )
+    np.testing.assert_allclose(bc, brandes_reference(g), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("engine_kind,overlap", [
+    ("sparse", "none"), ("pallas", "none"), ("pallas_sparse", "expand+fold"),
+])
+def test_graph_operands_land_on_their_own_devices(engine_kind, overlap):
+    """With a mesh, each device is handed only its own [R, C] cell of
+    every graph operand — nothing is staged whole on one device."""
+    from repro.core.distributed import distributed_graph_arrays
+    from repro.graphs.partition import partition_2d
+
+    mesh = _mesh((2, 2, 2), ("pod", "data", "model"))
+    part = partition_2d(gnp_graph(40, 0.1, seed=3), 2, 2)
+    for arr in distributed_graph_arrays(part, engine_kind, overlap, mesh=mesh):
+        shards = arr.addressable_shards
+        assert {s.device for s in shards} == set(mesh.devices.flat)
+        for s in shards:
+            assert s.data.shape[:2] == (1, 1)  # one grid cell per device
+            (i,), (j,) = (
+                range(arr.shape[0])[s.index[0]], range(arr.shape[1])[s.index[1]]
+            )
+            pod, row, col = np.argwhere(mesh.devices == s.device)[0]
+            assert (i, j) == (row, col)

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import brandes_reference, engine
 from repro.core.distributed import (
     distributed_betweenness_centrality,
@@ -125,7 +124,7 @@ def _ring_state(graph, engine_kind, overlap, R, C):
 
     owner = P(("model", "data"), None)  # chunk layout == identity vertex order
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=graph_specs + (P(("model", "data")), P()),

@@ -191,3 +191,112 @@ def test_bc_end_to_end_with_pallas_engine(engine_kind):
         g, batch_size=8, heuristics="h3", engine_kind=engine_kind
     )
     np.testing.assert_allclose(got.bc, brandes_reference(g), rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------------------- precision + dispatch
+def _dot_precisions(jaxpr):
+    """``precision`` of every dot_general in a jaxpr, kernel bodies and
+    nested jits included."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn.params["precision"])
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                if isinstance(sub, ClosedJaxpr):
+                    found += _dot_precisions(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    found += _dot_precisions(sub)
+    return found
+
+
+def _traversal_contractions():
+    """(name, fn, args) for every A @ x contraction of the traversal:
+    the Pallas kernels (traced, not run), their jnp references and the
+    operators' XLA dots."""
+    from repro.core.operators import (
+        DenseOperator,
+        DistributedPallasOperator,
+        DistributedPallasSparseOperator,
+        DistributedWeightedDenseOperator,
+        PallasDenseOperator,
+        WeightedDenseOperator,
+    )
+
+    n, s = 256, 128
+    a = jnp.zeros((n, n), jnp.float32)
+    st = jnp.zeros((n, s), jnp.float32)
+    dp = jnp.zeros((n, s), jnp.int32)
+    om = jnp.zeros((n,), jnp.float32)
+    tiles = jnp.zeros((4, 128, 128), jnp.float32)
+    idx = jnp.array([0, 0, 1, 1], jnp.int32), jnp.array([0, 1, 0, 1], jnp.int32)
+    geo = dict(chunk=n, R=1, C=1, row_axis="data", col_axis="model")
+    kw = dict(interpret=False)
+    return [
+        ("frontier_spmm", lambda: ops.frontier_spmm(a, st, dp, 1, **kw)),
+        ("dependency_spmm", lambda: ops.dependency_spmm(a, st, dp, st, om, 1, **kw)),
+        ("frontier_partial", lambda: ops.frontier_spmm_partial(a, st, dp, 1, acc=st, **kw)),
+        ("dependency_partial",
+         lambda: ops.dependency_spmm_partial(a, st, dp, st, om, 1, acc=st, **kw)),
+        ("frontier_sparse",
+         lambda: ops.frontier_spmm_sparse(tiles, *idx, st, dp, 1, m=n, **kw)),
+        ("dependency_sparse",
+         lambda: ops.dependency_spmm_sparse(tiles, *idx, st, dp, st, om, 1, m=n, **kw)),
+        ("frontier_ref", lambda: ref.frontier_spmm_ref(a, st, dp, 1)),
+        ("dependency_ref", lambda: ref.dependency_spmm_ref(a, st, dp, st, om, 1)),
+        ("frontier_partial_ref", lambda: ref.frontier_partial_ref(a, st, dp, 1)),
+        ("dependency_partial_ref",
+         lambda: ref.dependency_partial_ref(a, st, dp, st, om, 1)),
+        ("DenseOperator.apply", lambda: DenseOperator(a).apply(st)),
+        ("PallasDenseOperator.apply", lambda: PallasDenseOperator(a).apply(st)),
+        ("WeightedDenseOperator.apply", lambda: WeightedDenseOperator(a, 1.0).apply(st)),
+        ("WeightedDenseOperator.sigma_step",
+         lambda: WeightedDenseOperator(a, 1.0).sigma_step(st, st)),
+        ("WeightedDenseOperator.delta_step",
+         lambda: WeightedDenseOperator(a, 1.0).delta_step(st, st)),
+        ("DistributedPallasOperator._local",
+         lambda: DistributedPallasOperator(a, **geo)._local(st)),
+        ("DistributedPallasSparseOperator._local",
+         lambda: DistributedPallasSparseOperator(tiles, *idx, **geo)._local(st)),
+        ("DistributedWeightedDenseOperator._local",
+         lambda: DistributedWeightedDenseOperator(a, delta=1.0, **geo)._local(st)),
+    ]
+
+
+@pytest.mark.parametrize(
+    "name,fn", _traversal_contractions(), ids=[c[0] for c in _traversal_contractions()]
+)
+def test_traversal_contractions_pin_f32_precision(name, fn):
+    """Every A @ x of the traversal asks for full f32 precision.
+
+    The 0/1 adjacency is exact in bf16 but σ and g = (1+δ+ω)/σ are not;
+    the TPU's default precision would round them to bf16 on the MXU.  A
+    dot that drops the precision argument fails here, on the CPU.
+    """
+    import jax
+
+    precisions = _dot_precisions(jax.make_jaxpr(fn)().jaxpr)
+    assert precisions, f"{name}: no contraction traced"
+    highest = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    assert all(p == highest for p in precisions), (name, precisions)
+
+
+def test_interpret_mode_follows_backend(monkeypatch):
+    """Kernels compile on TPU, interpret on the CPU backend, and refuse
+    any other backend instead of interpreting there in silence."""
+    import jax
+
+    assert ops.resolve_interpret(None) is True  # tests run on the CPU backend
+    assert ops.resolve_interpret(False) is False  # an explicit choice stands
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    assert ops.resolve_interpret(True) is True
+    with pytest.raises(RuntimeError, match="TPU programs"):
+        ops.resolve_interpret(None)
+    # a shape no other test traces, so no cached trace can answer for it
+    A, sigma, depth, _, _ = _bc_state(24, 3, 0, 1)
+    with pytest.raises(RuntimeError, match="TPU programs"):
+        ops.frontier_spmm_partial(A, sigma, depth, 1)

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import engine
 from repro.core.operators import (
     DenseOperator,
@@ -160,7 +159,7 @@ def _distributed_state(graph, engine_kind, R=2, C=4):
 
     owner = P(("model", "data"), None)  # chunk layout == identity vertex order
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=graph_specs + (P(("model", "data")), P()),

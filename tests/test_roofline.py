@@ -141,7 +141,6 @@ def test_ring_lowering_counted_by_parser():
         pytest.skip("needs 8 devices")
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((8,), ("data",))
@@ -155,7 +154,7 @@ def test_ring_lowering_counted_by_parser():
         return acc
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False
         )
     )
@@ -166,3 +165,27 @@ def test_ring_lowering_counted_by_parser():
     ]
     assert permutes, text[:2000]
     assert ring_steps(permutes) >= 7
+
+
+@pytest.mark.parametrize(
+    "platform,kind,want",
+    [
+        ("cpu", "cpu", "tpu-v5e"),  # CPU rehearsals plan as a v5e chip
+        ("tpu", "TPU v5 lite", "tpu-v5e"),
+        ("tpu", "TPU v9 imaginary", None),  # an unlisted chip is an error
+        ("gpu", "NVIDIA H100", None),
+    ],
+)
+def test_hardware_peaks_keyed_by_device_kind(platform, kind, want):
+    import types
+
+    from repro.roofline.model import HARDWARE, device_hardware
+
+    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+    if want is None:
+        with pytest.raises(ValueError, match="no peak-rate entry"):
+            device_hardware(dev)
+    else:
+        assert device_hardware(dev).name == want
+    assert device_hardware().name == "tpu-v5e"  # the test backend is the CPU
+    assert all(spec.peak_bf16_flops > 0 for spec in HARDWARE.values())

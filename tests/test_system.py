@@ -155,10 +155,53 @@ def test_bc_driver_checkpoint_kill_and_resume(tmp_path):
         )
 
 
-def test_bc_launcher_cli(tmp_path, capsys):
+@pytest.fixture
+def compile_cache_config():
+    """Restore JAX's persistent-cache settings after a test changes them."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    saved = {
+        name: getattr(jax.config, name)
+        for name in (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+        )
+    }
+    yield
+    for name, value in saved.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_placement(tmp_path, monkeypatch, compile_cache_config, from_env):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says, and only
+    there; unset, to the checkout's fixed .jax-cache."""
+    import pathlib
+
+    from repro.launch.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
+
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+        want = str(tmp_path / "cc")
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(CHECKOUT_CACHE_DIR)
+        assert CHECKOUT_CACHE_DIR == pathlib.Path(__file__).resolve().parents[1] / ".jax-cache"
+    assert enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    # small programs are stored too: the kernels must be found again
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    if from_env:
+        jax.jit(lambda x: x * 3 + 1)(np.arange(7.0)).block_until_ready()
+        assert any((tmp_path / "cc").iterdir())
+
+
+def test_bc_launcher_cli(tmp_path, monkeypatch, compile_cache_config):
     import sys
     from repro.launch import bc as bc_cli
 
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax-cache"))
     out = tmp_path / "scores.npy"
     argv = sys.argv
     sys.argv = [

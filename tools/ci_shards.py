@@ -41,6 +41,7 @@ SHARDS: dict[str, tuple[str, ...]] = {
         "tests/test_hybrid.py",
         "tests/test_serving.py",
         "tests/test_roofline.py",
+        "tests/test_tpu_compile.py",
     ),
     "engines": (  # ~103s
         "tests/test_operators.py",
@@ -49,6 +50,7 @@ SHARDS: dict[str, tuple[str, ...]] = {
         "tests/test_bc_core.py",
         "tests/test_properties.py",
         "tests/test_system.py",
+        "tests/test_chip_smoke.py",
     ),
     "system": (  # ~106s
         "tests/test_autotune.py",
