@@ -81,6 +81,7 @@ from repro.core.operators import (
     normalize_overlap,
 )
 from repro.core.scheduler import Schedule, build_schedule
+from repro.core.spans import span, spanned
 from repro.graphs.graph import Graph
 from repro.graphs.partition import TwoDPartition, partition_2d
 from repro.roofline.model import (
@@ -95,6 +96,8 @@ __all__ = [
     "DIST_ENGINE_KINDS",
     "make_distributed_round_fn",
     "distributed_graph_arrays",
+    "graph_host_arrays",
+    "put_graph_arrays",
     "distributed_betweenness_centrality",
     "one_degree_reduce_distributed",
     "resolve_overlap",
@@ -218,27 +221,48 @@ def distributed_graph_arrays(
     axis.  Without it the arrays land on the default device (small
     graphs, tests); the round fn's jit then reshards them on every call.
     """
+    host = graph_host_arrays(
+        partition, engine_kind, overlap, tile=tile, dense_cells=dense_cells,
+        hybrid_threshold=hybrid_threshold, weights=weights,
+    )
+    return put_graph_arrays(host, mesh=mesh, row_axis=row_axis, col_axis=col_axis)
+
+
+def put_graph_arrays(
+    host: tuple[np.ndarray, ...],
+    *,
+    mesh: Mesh | None = None,
+    row_axis: str = "data",
+    col_axis: str = "model",
+) -> tuple[jax.Array, ...]:
+    """The host operands of :func:`graph_host_arrays` on the device(s):
+    each onto its ``(row_axis, col_axis)`` grid shard with ``mesh``, else
+    onto the default device."""
     if mesh is None:
-        put = jnp.asarray
-    else:
-        from jax.sharding import NamedSharding
+        return tuple(jnp.asarray(a) for a in host)
+    from jax.sharding import NamedSharding
 
-        grid = NamedSharding(mesh, P(row_axis, col_axis))
+    grid = NamedSharding(mesh, P(row_axis, col_axis))
+    return tuple(jax.device_put(a, grid) for a in host)
 
-        def put(a):
-            return jax.device_put(np.asarray(a), grid)
 
+def graph_host_arrays(
+    partition: TwoDPartition,
+    engine_kind: str,
+    overlap: str = "none",
+    tile: tuple[int, int] | None = None,
+    dense_cells: np.ndarray | None = None,
+    hybrid_threshold: float = 1.0,
+    weights: np.ndarray | None = None,
+) -> tuple[np.ndarray, ...]:
+    """The host (numpy) form of :func:`distributed_graph_arrays`: the
+    same operands, in the same order, before any is put on a device."""
     if engine_kind == "sparse":
         if weights is not None:
-            return (
-                put(partition.src_local),
-                put(partition.dst_local),
-                put(partition.arc_weights(weights)),
-            )
+            return (partition.src_local, partition.dst_local, partition.arc_weights(weights))
         if normalize_overlap(overlap) != "none":
-            ring_src, ring_dst = partition.ring_arcs()
-            return (put(ring_src), put(ring_dst))
-        return (put(partition.src_local), put(partition.dst_local))
+            return partition.ring_arcs()
+        return (partition.src_local, partition.dst_local)
     if engine_kind in ("pallas_sparse", "pallas_hybrid"):
         ring = weights is None and normalize_overlap(overlap) != "none"
         bm, bk = tile if tile is not None else (None, None)
@@ -254,28 +278,20 @@ def distributed_graph_arrays(
                 bm, bk, dense_cells=dense_cells, ring=ring, weights=weights
             )
             layout = hybrid.sparse
-            lead = (put(hybrid.blocks),)
+            lead = (hybrid.blocks,)
         if ring:
-            tiles = (
-                put(layout.ring_tiles),
-                put(layout.ring_tile_rows),
-                put(layout.ring_tile_cols),
-            )
+            tiles = (layout.ring_tiles, layout.ring_tile_rows, layout.ring_tile_cols)
         else:
-            tiles = (
-                put(layout.tiles),
-                put(layout.tile_rows),
-                put(layout.tile_cols),
-            )
+            tiles = (layout.tiles, layout.tile_rows, layout.tile_cols)
         if engine_kind == "pallas_hybrid":
-            return lead + tiles + (put(dense_cells.astype(np.int32)),)
+            return lead + tiles + (dense_cells.astype(np.int32),)
         return tiles
     if weights is not None:
-        return (put(partition.dense_blocks(np.float32, weights=weights)),)
+        return (partition.dense_blocks(np.float32, weights=weights),)
     # 0/1 entries are exact in bf16: build the host blocks in the engine's
     # dtype so no f32 copy is shipped or converted
     dt = jnp.bfloat16 if engine_kind == "pallas_bf16" else np.float32
-    return (put(partition.dense_blocks(dt)),)
+    return (partition.dense_blocks(dt),)
 
 
 def estimate_device_footprint(
@@ -1050,6 +1066,7 @@ def make_distributed_round_fn(
     return jax.jit(shmapped)
 
 
+@spanned("bc.entry")
 def distributed_betweenness_centrality(
     graph: Graph,
     mesh: Mesh,
@@ -1120,6 +1137,12 @@ def distributed_betweenness_centrality(
     adjacency + state footprint is checked *before* compilation and an
     over-budget engine errors with a suggestion instead of OOMing
     mid-round.
+    Each call records a run of host spans (:mod:`repro.core.spans`;
+    ``BCResult.spans``): the root ``bc.entry``, set-up
+    ``bc.setup.schedule`` (sampling plan, schedule), ``.partition``
+    (2-D partition, autotune when on, tile counts, overlap and memory
+    resolution), ``.layout`` (the host operand layout) and ``.transfer``
+    (the puts until the operands are resident), then the driver's.
     ``autotune`` (:data:`repro.autotune.AUTOTUNE_MODES`) swaps the
     roofline guesses behind the tile pick, the hybrid cell choice,
     ``overlap="auto"`` and the straggler prior for cached measurements
@@ -1213,23 +1236,6 @@ def distributed_betweenness_centrality(
         plan_sampling,
     )
 
-    sample_plan = plan_sampling(
-        eligible_roots(graph), sampling, sample_frac, sample_k, sample_seed
-    )
-    if sample_plan.mode != "off" and heuristics != "h0":
-        raise ValueError(
-            "sampling requires heuristics='h0': the 1-/2-degree analytic "
-            "corrections are not per-root additive, so a sampled run "
-            "could not be rescaled into an unbiased estimator"
-        )
-    if stop_rule is not None and sample_plan.mode == "off":
-        raise ValueError(
-            "a stop_rule truncates the schedule, which is only meaningful "
-            "as a rescaled estimate; pass sampling='fixed' or 'adaptive'"
-        )
-    if sample_plan.mode == "adaptive" and stop_rule is None:
-        stop_rule = AdaptiveStopRule()
-
     autotune = normalize_autotune(autotune)
     integrity = normalize_integrity(integrity)
     if weighted:
@@ -1270,73 +1276,91 @@ def distributed_betweenness_centrality(
             raise ValueError(f"delta must be positive and finite, got {delta}")
     elif delta is not None:
         raise ValueError("delta is only meaningful with weighted=True")
-    schedule, prep, residual, omega_i = build_schedule(
-        graph, batch_size=batch_size, heuristics=heuristics,
-        root_order="eccentricity" if autotune != "off" else "id",
-        roots=sample_plan.roots,
-    )
-    R, C, fr = _grid_axes(mesh, row_axis, col_axis, replica_axis)
-    part = partition_2d(residual, R, C)
+    with span("bc.setup.schedule"):
+        sample_plan = plan_sampling(
+            eligible_roots(graph), sampling, sample_frac, sample_k, sample_seed
+        )
+        if sample_plan.mode != "off" and heuristics != "h0":
+            raise ValueError(
+                "sampling requires heuristics='h0': the 1-/2-degree analytic "
+                "corrections are not per-root additive, so a sampled run "
+                "could not be rescaled into an unbiased estimator"
+            )
+        if stop_rule is not None and sample_plan.mode == "off":
+            raise ValueError(
+                "a stop_rule truncates the schedule, which is only meaningful "
+                "as a rescaled estimate; pass sampling='fixed' or 'adaptive'"
+            )
+        if sample_plan.mode == "adaptive" and stop_rule is None:
+            stop_rule = AdaptiveStopRule()
+        schedule, prep, residual, omega_i = build_schedule(
+            graph, batch_size=batch_size, heuristics=heuristics,
+            root_order="eccentricity" if autotune != "off" else "id",
+            roots=sample_plan.roots,
+        )
+    with span("bc.setup.partition"):
+        R, C, fr = _grid_axes(mesh, row_axis, col_axis, replica_axis)
+        part = partition_2d(residual, R, C)
 
-    plan = None
-    if autotune != "off" and schedule.rounds:
-        sources0, derived0 = sample_batch(schedule, fr)
-        plan = plan_autotune(
-            part,
-            mesh,
-            engine_kind=engine_kind,
-            overlap=overlap,
-            batch_size=batch_size,
-            tile=tile,
-            mode=autotune,
-            cache=as_cache(autotune_cache),
-            graph=residual,
-            fr=fr,
-            row_axis=row_axis,
-            col_axis=col_axis,
-            replica_axis=replica_axis,
-            sources=sources0,
-            derived=derived0,
-            hybrid_threshold=hybrid_threshold,
-        )
-        if tile is None and plan.tile is not None:
-            tile = plan.tile
-        logger.info("autotune[%s]: %s", autotune, plan.report())
+        plan = None
+        if autotune != "off" and schedule.rounds:
+            sources0, derived0 = sample_batch(schedule, fr)
+            plan = plan_autotune(
+                part,
+                mesh,
+                engine_kind=engine_kind,
+                overlap=overlap,
+                batch_size=batch_size,
+                tile=tile,
+                mode=autotune,
+                cache=as_cache(autotune_cache),
+                graph=residual,
+                fr=fr,
+                row_axis=row_axis,
+                col_axis=col_axis,
+                replica_axis=replica_axis,
+                sources=sources0,
+                derived=derived0,
+                hybrid_threshold=hybrid_threshold,
+            )
+            if tile is None and plan.tile is not None:
+                tile = plan.tile
+            logger.info("autotune[%s]: %s", autotune, plan.report())
 
-    bm, bk = tile if tile is not None else (None, None)
-    # ONE host arc→tile counting pass (cached on the partition) serves
-    # the hybrid cell choice, the auto-overlap estimate, the memory
-    # guard, and the layout build that follows
-    tile_counts = (
-        part.blocked_sparse_counts(bm, bk)
-        if engine_kind in ("pallas_sparse", "pallas_hybrid")
-        else None
-    )
-    dense_cells = None
-    if engine_kind == "pallas_hybrid":
-        dense_cells, _ = hybrid_cell_choice(
-            part, bm, bk, threshold=hybrid_threshold, tile_counts=tile_counts,
-            measured=plan.cell_costs if plan is not None else None,
+        bm, bk = tile if tile is not None else (None, None)
+        # ONE host arc→tile counting pass (cached on the partition) serves
+        # the hybrid cell choice, the auto-overlap estimate, the memory
+        # guard, and the layout build that follows
+        tile_counts = (
+            part.blocked_sparse_counts(bm, bk)
+            if engine_kind in ("pallas_sparse", "pallas_hybrid")
+            else None
         )
-    if weighted:
-        # weighted collectives run the barrier schedule; overlap only
-        # keeps replicas in bucket-loop lockstep, so "auto" has nothing
-        # to price — resolve it to the barrier policy
-        if overlap == "auto":
-            logger.info("overlap='auto' -> 'none' (weighted rounds are barrier-schedule)")
-            overlap = "none"
-        overlap = normalize_overlap(overlap)
-    else:
-        overlap = resolve_overlap(
-            overlap, part, engine_kind, batch_size,
-            bm=bm, bk=bk, tile_counts=tile_counts, dense_cells=dense_cells,
-            measured=plan.overlap_level_s if plan is not None else None,
+        dense_cells = None
+        if engine_kind == "pallas_hybrid":
+            dense_cells, _ = hybrid_cell_choice(
+                part, bm, bk, threshold=hybrid_threshold, tile_counts=tile_counts,
+                measured=plan.cell_costs if plan is not None else None,
+            )
+        if weighted:
+            # weighted collectives run the barrier schedule; overlap only
+            # keeps replicas in bucket-loop lockstep, so "auto" has nothing
+            # to price — resolve it to the barrier policy
+            if overlap == "auto":
+                logger.info("overlap='auto' -> 'none' (weighted rounds are barrier-schedule)")
+                overlap = "none"
+            overlap = normalize_overlap(overlap)
+        else:
+            overlap = resolve_overlap(
+                overlap, part, engine_kind, batch_size,
+                bm=bm, bk=bk, tile_counts=tile_counts, dense_cells=dense_cells,
+                measured=plan.overlap_level_s if plan is not None else None,
+            )
+        check_device_memory(
+            part, engine_kind, batch_size, hbm_limit_bytes,
+            bm=bm, bk=bk, overlap="none" if weighted else overlap,
+            tile_counts=tile_counts, dense_cells=dense_cells,
         )
-    check_device_memory(
-        part, engine_kind, batch_size, hbm_limit_bytes,
-        bm=bm, bk=bk, overlap="none" if weighted else overlap,
-        tile_counts=tile_counts, dense_cells=dense_cells,
-    )
 
     round_fn = make_distributed_round_fn(
         part,
@@ -1354,19 +1378,26 @@ def distributed_betweenness_centrality(
 
     from jax.sharding import NamedSharding
 
-    omega_pad = np.zeros(part.n_pad, np.float32)
-    omega_pad[: graph.n] = omega_i
-    # reorder omega into chunk-owner layout: flat position = chunk-id*chunk + off
-    # chunk ids are contiguous in vertex order, so identity layout works.
-    omega_dev = jax.device_put(
-        omega_pad, NamedSharding(mesh, P((col_axis, row_axis)))
-    )
-
-    graph_args = distributed_graph_arrays(
-        part, engine_kind, overlap, tile=tile, dense_cells=dense_cells,
-        weights=residual.w if weighted else None,
-        mesh=mesh, row_axis=row_axis, col_axis=col_axis,
-    )
+    with span("bc.setup.layout"):
+        omega_pad = np.zeros(part.n_pad, np.float32)
+        omega_pad[: graph.n] = omega_i
+        host_args = graph_host_arrays(
+            part, engine_kind, overlap, tile=tile, dense_cells=dense_cells,
+            weights=residual.w if weighted else None,
+        )
+    nbytes = omega_pad.nbytes + sum(a.nbytes for a in host_args)
+    with span("bc.setup.transfer", bytes=nbytes):
+        # reorder omega into chunk-owner layout: flat position = chunk-id*chunk + off
+        # chunk ids are contiguous in vertex order, so identity layout works.
+        omega_dev = jax.device_put(
+            omega_pad, NamedSharding(mesh, P((col_axis, row_axis)))
+        )
+        graph_args = put_graph_arrays(
+            host_args, mesh=mesh, row_axis=row_axis, col_axis=col_axis
+        )
+        # resident before block 1 is dispatched, so the span ends with the copy
+        jax.block_until_ready((omega_dev, graph_args))
+    del host_args
 
     def block_fn(sources, derived):
         return round_fn(*graph_args, omega_dev, sources, derived)

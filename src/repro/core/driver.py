@@ -50,7 +50,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import engine
+from repro.core import engine, spans
+from repro.core.spans import span
 from repro.core.heuristics.one_degree import OneDegreeReduction, leaf_correction
 from repro.core.heuristics.two_degree import derive_two_degree_columns
 from repro.core.operators import TraversalOperator, as_operator
@@ -362,11 +363,11 @@ class BCResult:
     rounds_run: int
     forward_columns: int  # explicit BFS columns actually traversed
     backward_columns: int  # dependency columns (explicit + derived)
-    wall_s: float = 0.0  # host wall time of the round loop
-    block_times: list[float] | None = None  # per-dispatch-block seconds
-    #   (profile / straggler modes only — the driver blocks per block to
-    #   measure, so async dispatch is disabled; use for benchmarking and
-    #   scheduling, not peak-throughput production)
+    wall_s: float = 0.0  # seconds of the round loop (the bc.driver.run span)
+    block_times: list[float] | None = None  # seconds of each dispatch
+    #   block (its bc.driver.block span).  They time the device only where
+    #   the loop waits for each block — profile mode, straggler mode or a
+    #   stop rule; otherwise dispatch runs ahead and they time the host.
     straggler_stats: dict | None = None  # multi-ledger scheduler telemetry
     #   (straggler != "none" only): per-replica wall/rounds/levels,
     #   rounds stolen / re-dealt, speculative duplicates, idle estimate.
@@ -389,6 +390,8 @@ class BCResult:
     #   sub-dict (mode, checksum/audit failures, max residual, duplicate
     #   votes + verdicts, quarantined rounds, watchdog trips /
     #   re-dispatches / escalations).
+    spans: tuple | None = None  # the run's host spans (repro.core.spans),
+    #   root first; set by whichever call opened the run's root span
 
 
 def _unpack_block(out):
@@ -415,10 +418,19 @@ class BCDriver:
     ``round_fn``.  Legacy 3-tuple round functions (no ``levels``) are
     accepted under ``straggler="none"``.
 
-    ``profile=True`` blocks on every dispatch block and records its wall
-    seconds in ``BCResult.block_times`` (plus total ``wall_s``) — the
-    measurement mode the overlap benchmarks use; it defeats the async
-    pipeline, so leave it off in production.
+    ``profile=True`` waits for every dispatch block inside its
+    ``bc.driver.block`` span, so ``BCResult.block_times`` times the
+    device — the measurement mode the overlap benchmarks use; it defeats
+    the async pipeline, so leave it off in production.
+
+    Host spans (:mod:`repro.core.spans`): ``bc.driver.run`` around the
+    loop (``BCResult.wall_s``); per dispatch block ``bc.driver.block``
+    (``BCResult.block_times``) holding ``bc.driver.dispatch`` (sources to
+    the device, the round call, the accumulate), ``bc.driver.drain``
+    (the wait for a block, then its bookkeeping; in the straggler loop
+    the wait alone), ``bc.driver.collect`` (accumulator to the host),
+    ``bc.driver.stop_rule`` and ``bc.driver.checkpoint``; ``block``
+    attributes name the block.
 
     ``straggler`` (see :data:`STRAGGLER_POLICIES`) enables the
     multi-ledger sub-cluster scheduler; it requires ``round_fn`` to carry
@@ -942,13 +954,14 @@ class BCDriver:
 
     def _collect_bc(self, bc_acc) -> np.ndarray:
         """Checkpoint-seed + device accumulator, in per-vertex f64 space."""
-        bc = self._bc0.copy()
-        if bc_acc is not None:
-            dev = np.asarray(jax.device_get(bc_acc), np.float64)
-            if dev.ndim > 1:  # sub-cluster replicas are additive (§3.3)
-                dev = dev.reshape(-1, dev.shape[-1]).sum(axis=0)
-            bc = bc + dev[: self.n]
-        return bc
+        with span("bc.driver.collect"):
+            bc = self._bc0.copy()
+            if bc_acc is not None:
+                dev = np.asarray(jax.device_get(bc_acc), np.float64)
+                if dev.ndim > 1:  # sub-cluster replicas are additive (§3.3)
+                    dev = dev.reshape(-1, dev.shape[-1]).sum(axis=0)
+                bc = bc + dev[: self.n]
+            return bc
 
     def _finalize(self, bc_acc, ns_by_root) -> np.ndarray:
         bc = self._collect_bc(bc_acc)
@@ -957,14 +970,18 @@ class BCDriver:
         return bc
 
     def run(self) -> BCResult:
-        if self.straggler != "none":
-            return self._run_straggler()
-        return self._run_static()
+        with span("bc.driver.run") as run_span:
+            if self.straggler != "none":
+                result = self._run_straggler()
+            else:
+                result = self._run_static()
+        result.wall_s = run_span.seconds
+        if run_span.parent is None:
+            result.spans = spans.run_of(run_span)
+        return result
 
     # --------------------------------------------- legacy (static) loop
     def _run_static(self) -> BCResult:
-        import time
-
         bc_acc = None
         inflight: collections.deque = collections.deque()
         ns_by_root: dict[int, float] = dict(self._ns0)
@@ -975,67 +992,72 @@ class BCDriver:
         blocks_done = 0
         stopped_early = False
         blocks_since_snapshot = 0
-        block_times: list[float] | None = [] if self.profile else None
-        t_start = time.perf_counter()
+        block_times: list[float] = []
 
         def drain_one():
-            ns_dev, roots_dev, rids = inflight.popleft()
-            roots_np = np.asarray(roots_dev)  # device_get: block boundary
-            ns_np = np.asarray(ns_dev, np.float64)
-            for r in range(roots_np.shape[0]):
-                for root, nv in zip(roots_np[r], ns_np[r]):
-                    if root >= 0:
-                        ns_by_root[int(root)] = float(nv)
-            # commit at drain, not dispatch: the round's contribution now
-            # exists on device, so a crash before this point re-deals it
-            if self.ledger is not None:
-                for rid in rids:
-                    self.ledger.try_commit(rid)
-            drained.extend(rids)
+            ns_dev, roots_dev, rids, block = inflight.popleft()
+            with span("bc.driver.drain", block=block):
+                roots_np = np.asarray(roots_dev)  # device_get: block boundary
+                ns_np = np.asarray(ns_dev, np.float64)
+                for r in range(roots_np.shape[0]):
+                    for root, nv in zip(roots_np[r], ns_np[r]):
+                        if root >= 0:
+                            ns_by_root[int(root)] = float(nv)
+                # commit at drain, not dispatch: the round's contribution now
+                # exists on device, so a crash before this point re-deals it
+                if self.ledger is not None:
+                    for rid in rids:
+                        self.ledger.try_commit(rid)
+                drained.extend(rids)
 
         def snapshot():
             # drain everything first so (bc, ns, committed) is a
             # consistent prefix — see fault_tolerance.BCCheckpoint.
-            while inflight:
-                drain_one()
-            self.checkpoint.save(
-                self._collect_bc(bc_acc), ns_by_root, drained, self._fingerprint,
-                stats=self._stats_state(),
-            )
-
-        for srcs, ders, live in self._blocks():
-            t_blk = time.perf_counter()
-            bc_blk, ns, roots, _levels, _integ = self._dispatch_block(srcs, ders)
-            if block_times is not None:  # profile: sync to time this block
-                jax.block_until_ready(bc_blk)
-                block_times.append(time.perf_counter() - t_blk)
-            bc_acc = bc_blk if bc_acc is None else self._accumulate(bc_acc, bc_blk)
-            inflight.append((ns, roots, live))
-            rounds_run += len(live)
-            fwd_cols += int((srcs >= 0).sum())
-            bwd_cols += int((srcs >= 0).sum() + (ders[:, :, 0] >= 0).sum())
-            while len(inflight) > self.max_inflight:
-                drain_one()
-            blocks_done += 1
-            blocks_since_snapshot += 1
-            if self.checkpoint is not None and (
-                blocks_since_snapshot >= self.checkpoint_every
-            ):
-                snapshot()
-                blocks_since_snapshot = 0
-            if self.stop_rule is not None:
-                # drain first so the accumulator the rule sees is exactly
-                # the committed prefix (what a checkpoint would hold)
+            with span("bc.driver.checkpoint"):
                 while inflight:
                     drain_one()
-                if self.stop_rule(self._collect_bc(bc_acc), blocks_done):
-                    stopped_early = True
-                    logger.info(
-                        "stop rule fired after %d dispatch blocks "
-                        "(%d rounds committed); halting dispatch",
-                        blocks_done, len(drained),
-                    )
-                    break
+                self.checkpoint.save(
+                    self._collect_bc(bc_acc), ns_by_root, drained, self._fingerprint,
+                    stats=self._stats_state(),
+                )
+
+        for srcs, ders, live in self._blocks():
+            block = blocks_done + 1
+            with span("bc.driver.block", block=block) as blk:
+                with span("bc.driver.dispatch", block=block):
+                    bc_blk, ns, roots, _levels, _integ = self._dispatch_block(srcs, ders)
+                    bc_acc = bc_blk if bc_acc is None else self._accumulate(bc_acc, bc_blk)
+                if self.profile:  # wait for the block inside its span
+                    jax.block_until_ready(bc_acc)
+                inflight.append((ns, roots, live, block))
+                rounds_run += len(live)
+                fwd_cols += int((srcs >= 0).sum())
+                bwd_cols += int((srcs >= 0).sum() + (ders[:, :, 0] >= 0).sum())
+                while len(inflight) > self.max_inflight:
+                    drain_one()
+                blocks_done = block
+                blocks_since_snapshot += 1
+                if self.checkpoint is not None and (
+                    blocks_since_snapshot >= self.checkpoint_every
+                ):
+                    snapshot()
+                    blocks_since_snapshot = 0
+                if self.stop_rule is not None:
+                    # drain first so the accumulator the rule sees is exactly
+                    # the committed prefix (what a checkpoint would hold)
+                    while inflight:
+                        drain_one()
+                    bc_now = self._collect_bc(bc_acc)
+                    with span("bc.driver.stop_rule", block=block):
+                        stopped_early = bool(self.stop_rule(bc_now, blocks_done))
+            block_times.append(blk.seconds)
+            if stopped_early:
+                logger.info(
+                    "stop rule fired after %d dispatch blocks "
+                    "(%d rounds committed); halting dispatch",
+                    blocks_done, len(drained),
+                )
+                break
         while inflight:
             drain_one()
         if self.checkpoint is not None:
@@ -1047,7 +1069,6 @@ class BCDriver:
             rounds_run=rounds_run,
             forward_columns=fwd_cols,
             backward_columns=bwd_cols,
-            wall_s=time.perf_counter() - t_start,
             block_times=block_times,
             stopped_early=stopped_early,
             stop_stats=getattr(self.stop_rule, "stats", None),
@@ -1090,8 +1111,6 @@ class BCDriver:
           tail, ``redeal`` re-packs every pending round when the EWMA
           ratio crosses ``straggler_factor``.
         """
-        import time
-
         from repro.distributed.fault_tolerance import ReplicaLostError
 
         fr = self.fr
@@ -1135,7 +1154,6 @@ class BCDriver:
             "idle_s_est": 0.0,
         }
         was_flagged = False
-        t_start = time.perf_counter()
 
         def flagged() -> bool:
             vals = [
@@ -1211,13 +1229,14 @@ class BCDriver:
                 )
 
         def snapshot():
-            self.checkpoint.save(
-                self._collect_bc(bc_acc),
-                ns_by_root,
-                [led.state() for led in self.ledgers],
-                self._fingerprint,
-                stats=self._stats_state(),
-            )
+            with span("bc.driver.checkpoint"):
+                self.checkpoint.save(
+                    self._collect_bc(bc_acc),
+                    ns_by_root,
+                    [led.state() for led in self.ledgers],
+                    self._fingerprint,
+                    stats=self._stats_state(),
+                )
 
         while any(queues):
             alive = [r for r in range(fr) if r not in self._dead_lanes]
@@ -1284,194 +1303,198 @@ class BCDriver:
                     ders[r] = rounds[rid].derived
 
             # ------------------------------------- dispatch + observe
-            t_blk = time.perf_counter()
-            try:
-                out = self._dispatch_block(srcs, ders)
-            except ReplicaLostError as e:
-                if int(getattr(e, "replica", -1)) < 0:
-                    # unattributed loss (the watchdog escalated a wedged
-                    # dispatch without knowing *which* lane hung): suspect
-                    # the slowest live lane by EWMA — the one most likely
-                    # to be the straggling/wedged participant
-                    cands = [
-                        r for r in alive if lane_rids[r] is not None
-                    ] or alive
-                    suspect = max(cands, key=est)
-                    e = ReplicaLostError(
-                        suspect,
-                        f"{e}; suspecting replica {suspect} "
-                        f"(slowest EWMA among the dispatched lanes)",
-                    )
-                on_replica_loss(e, lane_rids, duplicate)
-                continue
-            bc_blk, ns_dev, roots_dev, levels_dev, _integ = out
-            if levels_dev is None:
-                raise ValueError(
-                    "straggler scheduling needs a round_fn returning "
-                    "(bc, ns, roots, levels); got a legacy 3-tuple"
-                )
-            jax.block_until_ready(bc_blk)
-            wall = time.perf_counter() - t_blk
-            block_times.append(wall)
-            if bc_blk.shape[0] != fr:
-                raise ValueError(
-                    f"straggler scheduling needs a per-replica bc block "
-                    f"(leading dim {fr}); got shape {tuple(bc_blk.shape)}"
-                )
-            levels_np = np.asarray(levels_dev).reshape(-1).astype(np.int64)
-            # duplicate lanes ran work they will discard: they get no wall
-            # attribution and no EWMA update (their "cost" belongs to the
-            # round's owner lane, which is also in this block)
-            own = [
-                r for r in range(fr)
-                if lane_rids[r] is not None and not duplicate[r]
-            ]
-            lv_total = int(levels_np[own].sum())
-            lv_max = int(levels_np[own].max()) if own else 0
-            for r in own:
-                share = (
-                    levels_np[r] / lv_total if lv_total > 0 else 1.0 / len(own)
-                )
-                obs = wall * float(share)
-                ewma[r] = (
-                    obs
-                    if ewma[r] is None and prior is None
-                    else _EWMA_ALPHA * obs
-                    + (1.0 - _EWMA_ALPHA) * (ewma[r] if ewma[r] is not None else prior)
-                )
-                observed[r] = True
-                stats["per_replica_wall_s"][r] += obs
-                stats["per_replica_levels"][r] += int(levels_np[r])
-                stats["idle_levels"] += lv_max - int(levels_np[r])
-            if lv_max > 0 and own:
-                idle_frac = sum(lv_max - int(levels_np[r]) for r in own) / (
-                    len(own) * lv_max
-                )
-                stats["idle_s_est"] += wall * idle_frac
-
-            # ---------------------- duplicate vote (free DMR, steal tail)
-            # a speculatively duplicated round ran the identical
-            # deterministic computation on two replica lanes — compare
-            # their bc digests; a mismatch means one lane produced
-            # silently corrupt data, so neither copy can be trusted:
-            # quarantine the round (no commit, both lanes masked to zero)
-            # and re-dispatch it to its owner as the tie-breaker vote.
-            quarantined_rids: set[int] = set()
-            lane_sums = None
-            if self.integrity != "off" and (
-                any(duplicate) or self._pending_votes
-            ):
-                lane_sums = np.asarray(
-                    jax.device_get(self._block_digest(bc_blk)[0]), np.float64
-                ).reshape(-1)
-            if lane_sums is not None and any(duplicate):
-                ist = self.recovery["integrity"]
-                for r in range(fr):
-                    if not duplicate[r]:
-                        continue
-                    rid = lane_rids[r]
-                    owner = next(
-                        o for o in range(fr)
-                        if lane_rids[o] == rid and not duplicate[o]
-                    )
-                    ist["votes"] += 1
-                    vscale = max(
-                        1.0, abs(lane_sums[owner]), abs(lane_sums[r])
-                    )
-                    if (
-                        abs(lane_sums[r] - lane_sums[owner])
-                        > VOTE_RTOL * vscale
-                    ):
-                        ist["vote_mismatches"] += 1
-                        if rid in quarantined_rids:
-                            continue  # already requeued by another copy
-                        ist["quarantined_rounds"] += 1
-                        quarantined_rids.add(rid)
-                        self._pending_votes[rid] = {
-                            "owner": float(lane_sums[owner]),
-                            "duplicate": float(lane_sums[r]),
-                        }
-                        queues[owner].insert(0, rid)
-                        logger.warning(
-                            "duplicate-vote mismatch on round %d "
-                            "(owner lane %d sum %.6g vs duplicate lane %d "
-                            "sum %.6g); round quarantined, re-dispatching "
-                            "as tie-breaker",
-                            rid, owner, lane_sums[owner], r, lane_sums[r],
+            block = len(block_times) + 1
+            with span("bc.driver.block", block=block) as blk:
+                try:
+                    with span("bc.driver.dispatch", block=block):
+                        out = self._dispatch_block(srcs, ders)
+                except ReplicaLostError as e:
+                    if int(getattr(e, "replica", -1)) < 0:
+                        # unattributed loss (the watchdog escalated a wedged
+                        # dispatch without knowing *which* lane hung): suspect
+                        # the slowest live lane by EWMA — the one most likely
+                        # to be the straggling/wedged participant
+                        cands = [
+                            r for r in alive if lane_rids[r] is not None
+                        ] or alive
+                        suspect = max(cands, key=est)
+                        e = ReplicaLostError(
+                            suspect,
+                            f"{e}; suspecting replica {suspect} "
+                            f"(slowest EWMA among the dispatched lanes)",
                         )
-
-            # -------------------------- drain: commit-or-discard + add
-            # originals commit before their speculative duplicates, so a
-            # backup copy never out-commits the lane that owns the round
-            # (keeps duplicates_discarded and per-replica attribution
-            # honest; exactly-once holds in either order)
-            mask = np.zeros(fr, np.float32)
-            roots_np = np.asarray(roots_dev)
-            ns_np = np.asarray(ns_dev, np.float64)
-            for r in sorted(range(fr), key=lambda r: duplicate[r]):
-                rid = lane_rids[r]
-                if rid is None or rid in quarantined_rids:
+                    on_replica_loss(e, lane_rids, duplicate)
                     continue
-                if self._try_commit(r, rid):
-                    mask[r] = 1.0
-                    rounds_run += 1
-                    stats["per_replica_rounds"][r] += 1
-                    fwd_cols += int((srcs[r] >= 0).sum())
-                    bwd_cols += int(
-                        (srcs[r] >= 0).sum() + (ders[r, :, 0] >= 0).sum()
+                bc_blk, ns_dev, roots_dev, levels_dev, _integ = out
+                if levels_dev is None:
+                    raise ValueError(
+                        "straggler scheduling needs a round_fn returning "
+                        "(bc, ns, roots, levels); got a legacy 3-tuple"
                     )
-                    for root, nv in zip(roots_np[r], ns_np[r]):
-                        if root >= 0:
-                            ns_by_root[int(root)] = float(nv)
-                    pend = self._pending_votes.pop(rid, None)
-                    if pend is not None and lane_sums is not None:
-                        # tie-breaker verdict: which original lane agreed
-                        # with this clean recompute (i.e. was correct)
-                        tie = float(lane_sums[r])
+                with span("bc.driver.drain", block=block):
+                    jax.block_until_ready(bc_blk)
+                wall = blk.elapsed()
+                if bc_blk.shape[0] != fr:
+                    raise ValueError(
+                        f"straggler scheduling needs a per-replica bc block "
+                        f"(leading dim {fr}); got shape {tuple(bc_blk.shape)}"
+                    )
+                levels_np = np.asarray(levels_dev).reshape(-1).astype(np.int64)
+                # duplicate lanes ran work they will discard: they get no wall
+                # attribution and no EWMA update (their "cost" belongs to the
+                # round's owner lane, which is also in this block)
+                own = [
+                    r for r in range(fr)
+                    if lane_rids[r] is not None and not duplicate[r]
+                ]
+                lv_total = int(levels_np[own].sum())
+                lv_max = int(levels_np[own].max()) if own else 0
+                for r in own:
+                    share = (
+                        levels_np[r] / lv_total if lv_total > 0 else 1.0 / len(own)
+                    )
+                    obs = wall * float(share)
+                    ewma[r] = (
+                        obs
+                        if ewma[r] is None and prior is None
+                        else _EWMA_ALPHA * obs
+                        + (1.0 - _EWMA_ALPHA) * (ewma[r] if ewma[r] is not None else prior)
+                    )
+                    observed[r] = True
+                    stats["per_replica_wall_s"][r] += obs
+                    stats["per_replica_levels"][r] += int(levels_np[r])
+                    stats["idle_levels"] += lv_max - int(levels_np[r])
+                if lv_max > 0 and own:
+                    idle_frac = sum(lv_max - int(levels_np[r]) for r in own) / (
+                        len(own) * lv_max
+                    )
+                    stats["idle_s_est"] += wall * idle_frac
 
-                        def close(a, b):
-                            return abs(a - b) <= VOTE_RTOL * max(
-                                1.0, abs(a), abs(b)
+                # ---------------------- duplicate vote (free DMR, steal tail)
+                # a speculatively duplicated round ran the identical
+                # deterministic computation on two replica lanes — compare
+                # their bc digests; a mismatch means one lane produced
+                # silently corrupt data, so neither copy can be trusted:
+                # quarantine the round (no commit, both lanes masked to zero)
+                # and re-dispatch it to its owner as the tie-breaker vote.
+                quarantined_rids: set[int] = set()
+                lane_sums = None
+                if self.integrity != "off" and (
+                    any(duplicate) or self._pending_votes
+                ):
+                    lane_sums = np.asarray(
+                        jax.device_get(self._block_digest(bc_blk)[0]), np.float64
+                    ).reshape(-1)
+                if lane_sums is not None and any(duplicate):
+                    ist = self.recovery["integrity"]
+                    for r in range(fr):
+                        if not duplicate[r]:
+                            continue
+                        rid = lane_rids[r]
+                        owner = next(
+                            o for o in range(fr)
+                            if lane_rids[o] == rid and not duplicate[o]
+                        )
+                        ist["votes"] += 1
+                        vscale = max(
+                            1.0, abs(lane_sums[owner]), abs(lane_sums[r])
+                        )
+                        if (
+                            abs(lane_sums[r] - lane_sums[owner])
+                            > VOTE_RTOL * vscale
+                        ):
+                            ist["vote_mismatches"] += 1
+                            if rid in quarantined_rids:
+                                continue  # already requeued by another copy
+                            ist["quarantined_rounds"] += 1
+                            quarantined_rids.add(rid)
+                            self._pending_votes[rid] = {
+                                "owner": float(lane_sums[owner]),
+                                "duplicate": float(lane_sums[r]),
+                            }
+                            queues[owner].insert(0, rid)
+                            logger.warning(
+                                "duplicate-vote mismatch on round %d "
+                                "(owner lane %d sum %.6g vs duplicate lane %d "
+                                "sum %.6g); round quarantined, re-dispatching "
+                                "as tie-breaker",
+                                rid, owner, lane_sums[owner], r, lane_sums[r],
                             )
 
-                        matched = (
-                            "owner" if close(tie, pend["owner"])
-                            else "duplicate" if close(tie, pend["duplicate"])
-                            else "neither"
+                # -------------------------- drain: commit-or-discard + add
+                # originals commit before their speculative duplicates, so a
+                # backup copy never out-commits the lane that owns the round
+                # (keeps duplicates_discarded and per-replica attribution
+                # honest; exactly-once holds in either order)
+                mask = np.zeros(fr, np.float32)
+                roots_np = np.asarray(roots_dev)
+                ns_np = np.asarray(ns_dev, np.float64)
+                for r in sorted(range(fr), key=lambda r: duplicate[r]):
+                    rid = lane_rids[r]
+                    if rid is None or rid in quarantined_rids:
+                        continue
+                    if self._try_commit(r, rid):
+                        mask[r] = 1.0
+                        rounds_run += 1
+                        stats["per_replica_rounds"][r] += 1
+                        fwd_cols += int((srcs[r] >= 0).sum())
+                        bwd_cols += int(
+                            (srcs[r] >= 0).sum() + (ders[r, :, 0] >= 0).sum()
                         )
-                        self.recovery["integrity"]["vote_verdicts"].append(
-                            {"round": int(rid), "matched": matched}
-                        )
-                        logger.warning(
-                            "duplicate-vote tie-breaker for round %d: "
-                            "the %s lane was correct", rid, matched,
-                        )
-                elif duplicate[r]:
-                    stats["duplicates_discarded"] += 1
-            mask_dev = jnp.asarray(mask)
-            bc_acc = (
-                self._masked_scale(bc_blk, mask_dev)
-                if bc_acc is None
-                else self._masked_accumulate(bc_acc, bc_blk, mask_dev)
-            )
+                        for root, nv in zip(roots_np[r], ns_np[r]):
+                            if root >= 0:
+                                ns_by_root[int(root)] = float(nv)
+                        pend = self._pending_votes.pop(rid, None)
+                        if pend is not None and lane_sums is not None:
+                            # tie-breaker verdict: which original lane agreed
+                            # with this clean recompute (i.e. was correct)
+                            tie = float(lane_sums[r])
 
-            blocks_since_snapshot += 1
-            if self.checkpoint is not None and (
-                blocks_since_snapshot >= self.checkpoint_every
-            ):
-                snapshot()
-                blocks_since_snapshot = 0
-            # the stop seam: commits already happened at this block's
-            # drain (exactly-once is settled), so halting here leaves a
-            # clean committed prefix for the checkpoint/re-deal to own
-            if self.stop_rule is not None and self.stop_rule(
-                self._collect_bc(bc_acc), len(block_times)
-            ):
-                stopped_early = True
+                            def close(a, b):
+                                return abs(a - b) <= VOTE_RTOL * max(
+                                    1.0, abs(a), abs(b)
+                                )
+
+                            matched = (
+                                "owner" if close(tie, pend["owner"])
+                                else "duplicate" if close(tie, pend["duplicate"])
+                                else "neither"
+                            )
+                            self.recovery["integrity"]["vote_verdicts"].append(
+                                {"round": int(rid), "matched": matched}
+                            )
+                            logger.warning(
+                                "duplicate-vote tie-breaker for round %d: "
+                                "the %s lane was correct", rid, matched,
+                            )
+                    elif duplicate[r]:
+                        stats["duplicates_discarded"] += 1
+                mask_dev = jnp.asarray(mask)
+                bc_acc = (
+                    self._masked_scale(bc_blk, mask_dev)
+                    if bc_acc is None
+                    else self._masked_accumulate(bc_acc, bc_blk, mask_dev)
+                )
+
+                blocks_since_snapshot += 1
+                if self.checkpoint is not None and (
+                    blocks_since_snapshot >= self.checkpoint_every
+                ):
+                    snapshot()
+                    blocks_since_snapshot = 0
+                # the stop seam: commits already happened at this block's
+                # drain (exactly-once is settled), so halting here leaves a
+                # clean committed prefix for the checkpoint/re-deal to own
+                if self.stop_rule is not None:
+                    bc_now = self._collect_bc(bc_acc)
+                    with span("bc.driver.stop_rule", block=block):
+                        stopped_early = bool(self.stop_rule(bc_now, block))
+            block_times.append(blk.seconds)
+            if stopped_early:
                 logger.info(
                     "stop rule fired after %d dispatch blocks "
                     "(%d rounds committed); halting dispatch",
-                    len(block_times), rounds_run,
+                    block, rounds_run,
                 )
                 break
 
@@ -1479,7 +1502,7 @@ class BCDriver:
             snapshot()
         logger.info(
             "straggler=%s: %d rounds, %d stolen, %d re-dealt (%d events), "
-            "%d/%d duplicates discarded, idle ≈ %.3fs of %.3fs wall",
+            "%d/%d duplicates discarded, idle ≈ %.3fs of %.3fs in blocks",
             self.straggler,
             rounds_run,
             stats["rounds_stolen"],
@@ -1488,7 +1511,7 @@ class BCDriver:
             stats["duplicates_discarded"],
             stats["duplicates_dispatched"],
             stats["idle_s_est"],
-            time.perf_counter() - t_start,
+            sum(block_times),
         )
         return BCResult(
             bc=self._finalize(bc_acc, ns_by_root),
@@ -1496,7 +1519,6 @@ class BCDriver:
             rounds_run=rounds_run,
             forward_columns=fwd_cols,
             backward_columns=bwd_cols,
-            wall_s=time.perf_counter() - t_start,
             block_times=block_times,
             stopped_early=stopped_early,
             stop_stats=getattr(self.stop_rule, "stats", None),
