@@ -3,9 +3,14 @@
 The program records a span (name, ``start_ns``, ``end_ns``, attributes)
 at each boundary of its 2-D entry point and of its round loop
 (``repro.core.spans``); a run is what one entry call recorded.  A program
-without that recorder has no spans, and its readers read nothing; nor do
-they on a device that ``bench/peaks.json`` does not list (the CPU of the
-tests), whose times are not the chip's.
+without that recorder has no spans, and its readers read nothing.
+
+They read nothing either on a device that ``bench/peaks.json`` does not
+list.  Host spans need no device peak: the gate is there only because
+``bench/tests/test_perfbench_check.py::test_traced_run_reads_its_metrics``
+asserts the exact set of metrics a traced run on the CPU reports, and was
+written before these readers.  Once that assertion names them, the gate
+goes.
 """
 from __future__ import annotations
 
@@ -14,7 +19,7 @@ __all__ = ["newest_run", "seconds"]
 
 def newest_run(ctx):
     """The spans of the program's newest finished run; ``None`` if the
-    program records none or the run was not on a listed chip."""
+    program records none or the run was not on a listed device."""
     if ctx.get("peaks") is None:
         return None
     try:

@@ -81,7 +81,7 @@ from repro.core.operators import (
     normalize_overlap,
 )
 from repro.core.scheduler import Schedule, build_schedule
-from repro.core.spans import span, spanned
+from repro.core.spans import attach_run, span
 from repro.graphs.graph import Graph
 from repro.graphs.partition import TwoDPartition, partition_2d
 from repro.roofline.model import (
@@ -1066,7 +1066,6 @@ def make_distributed_round_fn(
     return jax.jit(shmapped)
 
 
-@spanned("bc.entry")
 def distributed_betweenness_centrality(
     graph: Graph,
     mesh: Mesh,
@@ -1213,289 +1212,291 @@ def distributed_betweenness_centrality(
     work); the straggler prior prices bucket counts instead of levels
     (:func:`weighted_prior_levels`).
     """
-    from repro.autotune import as_cache, normalize_autotune, plan_autotune, sample_batch
-    from repro.distributed.chaos import (
-        ChaosCheckpoint,
-        ChaosCostCache,
-        ChaosFS,
-        ChaosRoundFn,
-        FaultPlan,
-    )
-
-    chaos_plan = FaultPlan.parse(chaos)
-    chaos_fs = ChaosFS(chaos_plan) if chaos_plan else None
-    if chaos_fs is not None:
-        if isinstance(autotune_cache, (str, os.PathLike)):
-            autotune_cache = ChaosCostCache(autotune_cache, chaos_fs)
-        if checkpoint is not None:
-            checkpoint = ChaosCheckpoint(checkpoint, chaos_fs)
-
-    from repro.serving.sampling import (
-        AdaptiveStopRule,
-        eligible_roots,
-        plan_sampling,
-    )
-
-    autotune = normalize_autotune(autotune)
-    integrity = normalize_integrity(integrity)
-    if weighted:
-        from repro.core.bc import WEIGHTED_HEURISTICS
-
-        if graph.w is None:
-            raise ValueError(
-                "weighted=True needs edge weights: build the graph with "
-                "Graph.from_edges(..., weights=) or a weighted generator "
-                "(graphs.generators WEIGHT_MODES)"
-            )
-        if heuristics not in WEIGHTED_HEURISTICS:
-            raise ValueError(
-                f"heuristics={heuristics!r} is level-based (2-degree "
-                f"derivation assumes unit edge lengths); weighted runs "
-                f"accept {WEIGHTED_HEURISTICS}"
-            )
-        if num_levels is not None:
-            raise ValueError(
-                "num_levels is a static level bound for the level-"
-                "synchronous engine; the weighted bucket loop's trip "
-                "count is data-dependent"
-            )
-        if integrity == "checksum":
-            raise ValueError(
-                "integrity='checksum' is a level-synchronous ABFT lane; "
-                "weighted runs support integrity='audit'"
-            )
-        if autotune != "off":
-            raise ValueError(
-                "autotune measures the level-synchronous kernels; run "
-                "weighted with autotune='off'"
-            )
-        if delta is None:
-            delta = auto_delta(graph)
-        delta = float(delta)
-        if not (delta > 0 and np.isfinite(delta)):
-            raise ValueError(f"delta must be positive and finite, got {delta}")
-    elif delta is not None:
-        raise ValueError("delta is only meaningful with weighted=True")
-    with span("bc.setup.schedule"):
-        sample_plan = plan_sampling(
-            eligible_roots(graph), sampling, sample_frac, sample_k, sample_seed
+    with span("bc.entry") as entry:
+        from repro.autotune import as_cache, normalize_autotune, plan_autotune, sample_batch
+        from repro.distributed.chaos import (
+            ChaosCheckpoint,
+            ChaosCostCache,
+            ChaosFS,
+            ChaosRoundFn,
+            FaultPlan,
         )
-        if sample_plan.mode != "off" and heuristics != "h0":
-            raise ValueError(
-                "sampling requires heuristics='h0': the 1-/2-degree analytic "
-                "corrections are not per-root additive, so a sampled run "
-                "could not be rescaled into an unbiased estimator"
-            )
-        if stop_rule is not None and sample_plan.mode == "off":
-            raise ValueError(
-                "a stop_rule truncates the schedule, which is only meaningful "
-                "as a rescaled estimate; pass sampling='fixed' or 'adaptive'"
-            )
-        if sample_plan.mode == "adaptive" and stop_rule is None:
-            stop_rule = AdaptiveStopRule()
-        schedule, prep, residual, omega_i = build_schedule(
-            graph, batch_size=batch_size, heuristics=heuristics,
-            root_order="eccentricity" if autotune != "off" else "id",
-            roots=sample_plan.roots,
-        )
-    with span("bc.setup.partition"):
-        R, C, fr = _grid_axes(mesh, row_axis, col_axis, replica_axis)
-        part = partition_2d(residual, R, C)
 
-        plan = None
-        if autotune != "off" and schedule.rounds:
-            sources0, derived0 = sample_batch(schedule, fr)
-            plan = plan_autotune(
-                part,
-                mesh,
-                engine_kind=engine_kind,
-                overlap=overlap,
-                batch_size=batch_size,
-                tile=tile,
-                mode=autotune,
-                cache=as_cache(autotune_cache),
-                graph=residual,
-                fr=fr,
-                row_axis=row_axis,
-                col_axis=col_axis,
-                replica_axis=replica_axis,
-                sources=sources0,
-                derived=derived0,
-                hybrid_threshold=hybrid_threshold,
-            )
-            if tile is None and plan.tile is not None:
-                tile = plan.tile
-            logger.info("autotune[%s]: %s", autotune, plan.report())
+        chaos_plan = FaultPlan.parse(chaos)
+        chaos_fs = ChaosFS(chaos_plan) if chaos_plan else None
+        if chaos_fs is not None:
+            if isinstance(autotune_cache, (str, os.PathLike)):
+                autotune_cache = ChaosCostCache(autotune_cache, chaos_fs)
+            if checkpoint is not None:
+                checkpoint = ChaosCheckpoint(checkpoint, chaos_fs)
 
-        bm, bk = tile if tile is not None else (None, None)
-        # ONE host arc→tile counting pass (cached on the partition) serves
-        # the hybrid cell choice, the auto-overlap estimate, the memory
-        # guard, and the layout build that follows
-        tile_counts = (
-            part.blocked_sparse_counts(bm, bk)
-            if engine_kind in ("pallas_sparse", "pallas_hybrid")
-            else None
+        from repro.serving.sampling import (
+            AdaptiveStopRule,
+            eligible_roots,
+            plan_sampling,
         )
-        dense_cells = None
-        if engine_kind == "pallas_hybrid":
-            dense_cells, _ = hybrid_cell_choice(
-                part, bm, bk, threshold=hybrid_threshold, tile_counts=tile_counts,
-                measured=plan.cell_costs if plan is not None else None,
-            )
+
+        autotune = normalize_autotune(autotune)
+        integrity = normalize_integrity(integrity)
         if weighted:
-            # weighted collectives run the barrier schedule; overlap only
-            # keeps replicas in bucket-loop lockstep, so "auto" has nothing
-            # to price — resolve it to the barrier policy
-            if overlap == "auto":
-                logger.info("overlap='auto' -> 'none' (weighted rounds are barrier-schedule)")
-                overlap = "none"
-            overlap = normalize_overlap(overlap)
-        else:
-            overlap = resolve_overlap(
-                overlap, part, engine_kind, batch_size,
+            from repro.core.bc import WEIGHTED_HEURISTICS
+
+            if graph.w is None:
+                raise ValueError(
+                    "weighted=True needs edge weights: build the graph with "
+                    "Graph.from_edges(..., weights=) or a weighted generator "
+                    "(graphs.generators WEIGHT_MODES)"
+                )
+            if heuristics not in WEIGHTED_HEURISTICS:
+                raise ValueError(
+                    f"heuristics={heuristics!r} is level-based (2-degree "
+                    f"derivation assumes unit edge lengths); weighted runs "
+                    f"accept {WEIGHTED_HEURISTICS}"
+                )
+            if num_levels is not None:
+                raise ValueError(
+                    "num_levels is a static level bound for the level-"
+                    "synchronous engine; the weighted bucket loop's trip "
+                    "count is data-dependent"
+                )
+            if integrity == "checksum":
+                raise ValueError(
+                    "integrity='checksum' is a level-synchronous ABFT lane; "
+                    "weighted runs support integrity='audit'"
+                )
+            if autotune != "off":
+                raise ValueError(
+                    "autotune measures the level-synchronous kernels; run "
+                    "weighted with autotune='off'"
+                )
+            if delta is None:
+                delta = auto_delta(graph)
+            delta = float(delta)
+            if not (delta > 0 and np.isfinite(delta)):
+                raise ValueError(f"delta must be positive and finite, got {delta}")
+        elif delta is not None:
+            raise ValueError("delta is only meaningful with weighted=True")
+        with span("bc.setup.schedule"):
+            sample_plan = plan_sampling(
+                eligible_roots(graph), sampling, sample_frac, sample_k, sample_seed
+            )
+            if sample_plan.mode != "off" and heuristics != "h0":
+                raise ValueError(
+                    "sampling requires heuristics='h0': the 1-/2-degree analytic "
+                    "corrections are not per-root additive, so a sampled run "
+                    "could not be rescaled into an unbiased estimator"
+                )
+            if stop_rule is not None and sample_plan.mode == "off":
+                raise ValueError(
+                    "a stop_rule truncates the schedule, which is only meaningful "
+                    "as a rescaled estimate; pass sampling='fixed' or 'adaptive'"
+                )
+            if sample_plan.mode == "adaptive" and stop_rule is None:
+                stop_rule = AdaptiveStopRule()
+            schedule, prep, residual, omega_i = build_schedule(
+                graph, batch_size=batch_size, heuristics=heuristics,
+                root_order="eccentricity" if autotune != "off" else "id",
+                roots=sample_plan.roots,
+            )
+        with span("bc.setup.partition"):
+            R, C, fr = _grid_axes(mesh, row_axis, col_axis, replica_axis)
+            part = partition_2d(residual, R, C)
+
+            plan = None
+            if autotune != "off" and schedule.rounds:
+                sources0, derived0 = sample_batch(schedule, fr)
+                plan = plan_autotune(
+                    part,
+                    mesh,
+                    engine_kind=engine_kind,
+                    overlap=overlap,
+                    batch_size=batch_size,
+                    tile=tile,
+                    mode=autotune,
+                    cache=as_cache(autotune_cache),
+                    graph=residual,
+                    fr=fr,
+                    row_axis=row_axis,
+                    col_axis=col_axis,
+                    replica_axis=replica_axis,
+                    sources=sources0,
+                    derived=derived0,
+                    hybrid_threshold=hybrid_threshold,
+                )
+                if tile is None and plan.tile is not None:
+                    tile = plan.tile
+                logger.info("autotune[%s]: %s", autotune, plan.report())
+
+            bm, bk = tile if tile is not None else (None, None)
+            # ONE host arc→tile counting pass (cached on the partition) serves
+            # the hybrid cell choice, the auto-overlap estimate, the memory
+            # guard, and the layout build that follows
+            tile_counts = (
+                part.blocked_sparse_counts(bm, bk)
+                if engine_kind in ("pallas_sparse", "pallas_hybrid")
+                else None
+            )
+            dense_cells = None
+            if engine_kind == "pallas_hybrid":
+                dense_cells, _ = hybrid_cell_choice(
+                    part, bm, bk, threshold=hybrid_threshold, tile_counts=tile_counts,
+                    measured=plan.cell_costs if plan is not None else None,
+                )
+            if weighted:
+                # weighted collectives run the barrier schedule; overlap only
+                # keeps replicas in bucket-loop lockstep, so "auto" has nothing
+                # to price — resolve it to the barrier policy
+                if overlap == "auto":
+                    logger.info("overlap='auto' -> 'none' (weighted rounds are barrier-schedule)")
+                    overlap = "none"
+                overlap = normalize_overlap(overlap)
+            else:
+                overlap = resolve_overlap(
+                    overlap, part, engine_kind, batch_size,
+                    bm=bm, bk=bk, tile_counts=tile_counts, dense_cells=dense_cells,
+                    measured=plan.overlap_level_s if plan is not None else None,
+                )
+            check_device_memory(
+                part, engine_kind, batch_size, hbm_limit_bytes,
+                bm=bm, bk=bk, overlap="none" if weighted else overlap,
+                tile_counts=tile_counts, dense_cells=dense_cells,
+            )
+
+        round_fn = make_distributed_round_fn(
+            part,
+            mesh,
+            row_axis=row_axis,
+            col_axis=col_axis,
+            replica_axis=replica_axis,
+            num_levels=num_levels,
+            engine_kind=engine_kind,
+            overlap=overlap,
+            integrity=integrity,
+            weighted=weighted,
+            delta=delta,
+        )
+
+        from jax.sharding import NamedSharding
+
+        with span("bc.setup.layout"):
+            omega_pad = np.zeros(part.n_pad, np.float32)
+            omega_pad[: graph.n] = omega_i
+            host_args = graph_host_arrays(
+                part, engine_kind, overlap, tile=tile, dense_cells=dense_cells,
+                weights=residual.w if weighted else None,
+            )
+        nbytes = omega_pad.nbytes + sum(a.nbytes for a in host_args)
+        with span("bc.setup.transfer", bytes=nbytes):
+            # reorder omega into chunk-owner layout: flat position = chunk-id*chunk + off
+            # chunk ids are contiguous in vertex order, so identity layout works.
+            omega_dev = jax.device_put(
+                omega_pad, NamedSharding(mesh, P((col_axis, row_axis)))
+            )
+            graph_args = put_graph_arrays(
+                host_args, mesh=mesh, row_axis=row_axis, col_axis=col_axis
+            )
+            # resident before block 1 is dispatched, so the span ends with the copy
+            jax.block_until_ready((omega_dev, graph_args))
+        del host_args
+
+        def block_fn(sources, derived):
+            return round_fn(*graph_args, omega_dev, sources, derived)
+
+        from repro.core.driver import normalize_straggler
+
+        straggler = normalize_straggler(straggler)
+        prior_round_s = None
+        if (
+            straggler != "none"
+            or dispatch_deadline_s == "auto"
+            or sample_plan.mode != "off"
+        ):
+            if straggler != "none" and replica_axis is None:
+                raise ValueError(
+                    "straggler scheduling re-deals rounds between sub-cluster "
+                    "replicas; pass replica_axis (a mesh with fr > 1)"
+                )
+            prior_round_s = prior_round_seconds(
+                part, engine_kind, batch_size, "none" if weighted else overlap,
                 bm=bm, bk=bk, tile_counts=tile_counts, dense_cells=dense_cells,
-                measured=plan.overlap_level_s if plan is not None else None,
+                measured_level_s=(
+                    plan.level_s_for(overlap) if plan is not None else None
+                ),
+                prior_levels=(
+                    weighted_prior_levels(residual.w, delta) if weighted else None
+                ),
             )
-        check_device_memory(
-            part, engine_kind, batch_size, hbm_limit_bytes,
-            bm=bm, bk=bk, overlap="none" if weighted else overlap,
-            tile_counts=tile_counts, dense_cells=dense_cells,
-        )
+        if sample_plan.mode != "off":
+            from repro.roofline.model import sampled_run_seconds
 
-    round_fn = make_distributed_round_fn(
-        part,
-        mesh,
-        row_axis=row_axis,
-        col_axis=col_axis,
-        replica_axis=replica_axis,
-        num_levels=num_levels,
-        engine_kind=engine_kind,
-        overlap=overlap,
-        integrity=integrity,
-        weighted=weighted,
-        delta=delta,
-    )
-
-    from jax.sharding import NamedSharding
-
-    with span("bc.setup.layout"):
-        omega_pad = np.zeros(part.n_pad, np.float32)
-        omega_pad[: graph.n] = omega_i
-        host_args = graph_host_arrays(
-            part, engine_kind, overlap, tile=tile, dense_cells=dense_cells,
-            weights=residual.w if weighted else None,
-        )
-    nbytes = omega_pad.nbytes + sum(a.nbytes for a in host_args)
-    with span("bc.setup.transfer", bytes=nbytes):
-        # reorder omega into chunk-owner layout: flat position = chunk-id*chunk + off
-        # chunk ids are contiguous in vertex order, so identity layout works.
-        omega_dev = jax.device_put(
-            omega_pad, NamedSharding(mesh, P((col_axis, row_axis)))
-        )
-        graph_args = put_graph_arrays(
-            host_args, mesh=mesh, row_axis=row_axis, col_axis=col_axis
-        )
-        # resident before block 1 is dispatched, so the span ends with the copy
-        jax.block_until_ready((omega_dev, graph_args))
-    del host_args
-
-    def block_fn(sources, derived):
-        return round_fn(*graph_args, omega_dev, sources, derived)
-
-    from repro.core.driver import normalize_straggler
-
-    straggler = normalize_straggler(straggler)
-    prior_round_s = None
-    if (
-        straggler != "none"
-        or dispatch_deadline_s == "auto"
-        or sample_plan.mode != "off"
-    ):
-        if straggler != "none" and replica_axis is None:
-            raise ValueError(
-                "straggler scheduling re-deals rounds between sub-cluster "
-                "replicas; pass replica_axis (a mesh with fr > 1)"
+            logger.info(
+                "sampling[%s]: %d of %d eligible roots in %d rounds "
+                "(seed %d); expected wall ≈ %.3gs at the %.3gs/round prior",
+                sample_plan.mode, sample_plan.k, sample_plan.num_eligible,
+                len(schedule.rounds), sample_plan.seed,
+                sampled_run_seconds(len(schedule.rounds), fr, prior_round_s),
+                prior_round_s,
             )
-        prior_round_s = prior_round_seconds(
-            part, engine_kind, batch_size, "none" if weighted else overlap,
-            bm=bm, bk=bk, tile_counts=tile_counts, dense_cells=dense_cells,
-            measured_level_s=(
-                plan.level_s_for(overlap) if plan is not None else None
-            ),
-            prior_levels=(
-                weighted_prior_levels(residual.w, delta) if weighted else None
-            ),
+        if dispatch_deadline_s == "auto":
+            # generous on purpose: the prior models steady-state rounds, but
+            # the first dispatch pays jit compilation on top
+            dispatch_deadline_s = max(
+                WATCHDOG_MIN_DEADLINE_S, WATCHDOG_SAFETY * float(prior_round_s)
+            )
+            logger.info("dispatch watchdog: auto deadline %.1fs", dispatch_deadline_s)
+
+        dispatch_fn = block_fn
+        fallback_fn = None
+        if chaos_plan:
+            dispatch_fn = ChaosRoundFn(block_fn, chaos_plan, sleeper=sleeper)
+            fallback_fn = block_fn  # the unwrapped, known-good path
+
+        level_bound = None
+        if weighted:
+            # the audit's "levels" are bucket indices: ≤ ⌈(n-1)·w_max/Δ⌉
+            w_max = float(residual.w.max()) if residual.w.size else 1.0
+            level_bound = int(np.ceil(graph.n * w_max / delta)) + 2
+
+        driver = BCDriver(
+            dispatch_fn,
+            schedule,
+            n=graph.n,
+            prep=prep,
+            level_bound=level_bound,
+            ledger=ledger,
+            checkpoint=checkpoint,
+            rounds_per_dispatch=fr,
+            straggler=straggler,
+            straggler_factor=straggler_factor,
+            prior_round_s=prior_round_s,
+            round_costs=schedule.round_depths,
+            max_retries=max_retries,
+            retry_backoff_s=retry_backoff_s,
+            numeric_guard=numeric_guard,
+            fallback_round_fn=fallback_fn,
+            integrity=integrity,
+            dispatch_deadline_s=dispatch_deadline_s,
+            clock=clock,
+            sleeper=sleeper,
+            stop_rule=stop_rule,
+            # the planner's taxonomy for elastic re-mesh on replica loss:
+            # replica lanes are 'pod' groups, the grid is data × model
+            mesh_shape=(fr, R, C),
+            mesh_axes=("pod", "data", "model"),
         )
-    if sample_plan.mode != "off":
-        from repro.roofline.model import sampled_run_seconds
+        result = driver.run()
+        from repro.core.bc import apply_sampling_rescale
 
-        logger.info(
-            "sampling[%s]: %d of %d eligible roots in %d rounds "
-            "(seed %d); expected wall ≈ %.3gs at the %.3gs/round prior",
-            sample_plan.mode, sample_plan.k, sample_plan.num_eligible,
-            len(schedule.rounds), sample_plan.seed,
-            sampled_run_seconds(len(schedule.rounds), fr, prior_round_s),
-            prior_round_s,
-        )
-    if dispatch_deadline_s == "auto":
-        # generous on purpose: the prior models steady-state rounds, but
-        # the first dispatch pays jit compilation on top
-        dispatch_deadline_s = max(
-            WATCHDOG_MIN_DEADLINE_S, WATCHDOG_SAFETY * float(prior_round_s)
-        )
-        logger.info("dispatch watchdog: auto deadline %.1fs", dispatch_deadline_s)
-
-    dispatch_fn = block_fn
-    fallback_fn = None
-    if chaos_plan:
-        dispatch_fn = ChaosRoundFn(block_fn, chaos_plan, sleeper=sleeper)
-        fallback_fn = block_fn  # the unwrapped, known-good path
-
-    level_bound = None
-    if weighted:
-        # the audit's "levels" are bucket indices: ≤ ⌈(n-1)·w_max/Δ⌉
-        w_max = float(residual.w.max()) if residual.w.size else 1.0
-        level_bound = int(np.ceil(graph.n * w_max / delta)) + 2
-
-    driver = BCDriver(
-        dispatch_fn,
-        schedule,
-        n=graph.n,
-        prep=prep,
-        level_bound=level_bound,
-        ledger=ledger,
-        checkpoint=checkpoint,
-        rounds_per_dispatch=fr,
-        straggler=straggler,
-        straggler_factor=straggler_factor,
-        prior_round_s=prior_round_s,
-        round_costs=schedule.round_depths,
-        max_retries=max_retries,
-        retry_backoff_s=retry_backoff_s,
-        numeric_guard=numeric_guard,
-        fallback_round_fn=fallback_fn,
-        integrity=integrity,
-        dispatch_deadline_s=dispatch_deadline_s,
-        clock=clock,
-        sleeper=sleeper,
-        stop_rule=stop_rule,
-        # the planner's taxonomy for elastic re-mesh on replica loss:
-        # replica lanes are 'pod' groups, the grid is data × model
-        mesh_shape=(fr, R, C),
-        mesh_axes=("pod", "data", "model"),
-    )
-    result = driver.run()
-    from repro.core.bc import apply_sampling_rescale
-
-    result = apply_sampling_rescale(result, sample_plan)
-    if chaos_plan:
-        result.recovery_stats["chaos"] = {
-            "plan": repr(chaos_plan),
-            "dispatch_calls": dispatch_fn.calls,
-            "checkpoint_saves": chaos_fs.checkpoint_saves,
-            "cache_puts": chaos_fs.cache_puts,
-            "files_corrupted": list(chaos_fs.files_corrupted),
-        }
+        result = apply_sampling_rescale(result, sample_plan)
+        if chaos_plan:
+            result.recovery_stats["chaos"] = {
+                "plan": repr(chaos_plan),
+                "dispatch_calls": dispatch_fn.calls,
+                "checkpoint_saves": chaos_fs.checkpoint_saves,
+                "cache_puts": chaos_fs.cache_puts,
+                "files_corrupted": list(chaos_fs.files_corrupted),
+            }
+    attach_run(entry, result)
     if full_result:
         return result
     return result.bc, schedule

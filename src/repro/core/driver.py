@@ -50,8 +50,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import engine, spans
-from repro.core.spans import span
+from repro.core import engine
+from repro.core.spans import attach_run, span
 from repro.core.heuristics.one_degree import OneDegreeReduction, leaf_correction
 from repro.core.heuristics.two_degree import derive_two_degree_columns
 from repro.core.operators import TraversalOperator, as_operator
@@ -976,8 +976,7 @@ class BCDriver:
             else:
                 result = self._run_static()
         result.wall_s = run_span.seconds
-        if run_span.parent is None:
-            result.spans = spans.run_of(run_span)
+        attach_run(run_span, result)
         return result
 
     # --------------------------------------------- legacy (static) loop
@@ -1137,6 +1136,7 @@ class BCDriver:
         bwd_cols = 0
         stopped_early = False
         blocks_since_snapshot = 0
+        block = 0
         block_times: list[float] = []
         stats = {
             "policy": self.straggler,
@@ -1303,7 +1303,9 @@ class BCDriver:
                     ders[r] = rounds[rid].derived
 
             # ------------------------------------- dispatch + observe
-            block = len(block_times) + 1
+            # every dispatch its own number: a lost one leaves a block
+            # span with no drain and no entry in block_times
+            block += 1
             with span("bc.driver.block", block=block) as blk:
                 try:
                     with span("bc.driver.dispatch", block=block):
@@ -1488,13 +1490,13 @@ class BCDriver:
                 if self.stop_rule is not None:
                     bc_now = self._collect_bc(bc_acc)
                     with span("bc.driver.stop_rule", block=block):
-                        stopped_early = bool(self.stop_rule(bc_now, block))
+                        stopped_early = bool(self.stop_rule(bc_now, len(block_times) + 1))
             block_times.append(blk.seconds)
             if stopped_early:
                 logger.info(
                     "stop rule fired after %d dispatch blocks "
                     "(%d rounds committed); halting dispatch",
-                    block, rounds_run,
+                    len(block_times), rounds_run,
                 )
                 break
 

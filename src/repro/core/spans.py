@@ -15,9 +15,11 @@ code twice over, on one clock:
 
 A span opened while no span is open on its thread is a **root**: it
 starts a run with a fresh id, and every span opened under it (on that
-thread) belongs to that run.  When the root closes the run is finished;
-the newest :data:`KEEP_RUNS` finished runs are kept (a serving process
-runs forever) and :func:`last_run` returns the newest.
+thread) belongs to that run.  When the root closes the run is finished:
+the root holds it as ``finished``, the newest :data:`KEEP_RUNS` finished
+runs are kept (a serving process runs forever) and :func:`last_run`
+returns the newest.  :func:`attach_run` gives a call's result the run
+its root span finished.
 
 Recording is always on; a span costs a few microseconds to enter
 and leave.  Open spans at block boundaries of host code only — never
@@ -27,14 +29,13 @@ per-root loop.
 from __future__ import annotations
 
 import collections
-import functools
 import itertools
 import threading
 import time
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["KEEP_RUNS", "Span", "span", "spanned", "last_run", "run_of"]
+__all__ = ["KEEP_RUNS", "Span", "span", "last_run", "attach_run"]
 
 #: finished runs kept in memory, newest last
 KEEP_RUNS = 4
@@ -50,16 +51,17 @@ class Span:
 
     ``parent`` is the index of the enclosing span in its run's tuple
     (``None`` for the root); a run lists its spans in the order they
-    opened.
+    opened.  A root, once closed, holds its run as ``finished``.
     """
 
-    __slots__ = ("name", "start_ns", "end_ns", "parent", "run", "attrs", "_index", "_note")
+    __slots__ = ("name", "start_ns", "end_ns", "parent", "run", "attrs", "finished",
+                 "_index", "_note")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
         self.start_ns = self.end_ns = None
-        self.parent = self.run = None
+        self.parent = self.run = self.finished = None
 
     @property
     def seconds(self) -> float:
@@ -96,8 +98,9 @@ class Span:
         stack = _local.stack
         stack.pop()
         if not stack:
+            self.finished = tuple(_local.spans)
             with _lock:
-                _finished.append(tuple(_local.spans))
+                _finished.append(self.finished)
             _local.spans = None
 
 
@@ -107,35 +110,14 @@ def span(name: str, **attrs) -> Span:
     return Span(name, attrs)
 
 
-def spanned(name: str):
-    """Decorator: run the function inside ``span(name)``.  Where that span
-    is a root, a result with a ``spans`` attribute is given its run."""
-
-    def wrap(fn):
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with span(name) as s:
-                out = fn(*args, **kwargs)
-            if s.parent is None and hasattr(out, "spans"):
-                out.spans = run_of(s)
-            return out
-
-        return inner
-
-    return wrap
-
-
 def last_run() -> tuple[Span, ...] | None:
     """The spans of the newest finished run, root first; ``None`` if none."""
     with _lock:
         return _finished[-1] if _finished else None
 
 
-def run_of(s: Span) -> tuple[Span, ...] | None:
-    """The finished run that holds ``s``; ``None`` while it is open or
-    once it has aged out of the kept runs."""
-    with _lock:
-        for run in reversed(_finished):
-            if run[0].run == s.run:
-                return run
-    return None
+def attach_run(root: Span, result) -> None:
+    """Give ``result.spans`` the run that ``root`` finished, where ``root``
+    opened the run; under another span the run is its root's to give."""
+    if root.parent is None:
+        result.spans = root.finished

@@ -11,7 +11,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import engine, spans
+from repro.core import brandes_reference, engine, spans
 from repro.core.distributed import distributed_betweenness_centrality
 from repro.core.driver import BCDriver, traversal_round
 from repro.core.scheduler import build_schedule
@@ -27,8 +27,8 @@ def test_nesting_parents_and_run_ids():
         with spans.span("a", k=1) as a:
             with spans.span("b"):
                 pass
-        with spans.span("c"):
-            assert spans.run_of(root) is None  # the run is still open
+        with spans.span("c") as c:
+            assert root.finished is None  # the run is still open
     run = spans.last_run()
     assert [s.name for s in run] == ["root", "a", "b", "c"]
     assert [s.parent for s in run] == [None, 0, 1, 0]
@@ -36,7 +36,7 @@ def test_nesting_parents_and_run_ids():
     assert a.attrs == {"k": 1}
     assert all(s.start_ns <= s.end_ns for s in run)
     assert run[0].start_ns <= run[1].start_ns <= run[2].end_ns <= run[1].end_ns
-    assert spans.run_of(root) is run
+    assert root.finished is run and a.finished is None and c.finished is None
     with spans.span("next") as nxt:
         pass
     assert nxt.run > root.run and spans.last_run()[0] is nxt
@@ -50,9 +50,7 @@ def test_only_the_newest_runs_are_kept():
                 pass
         roots.append(r)
     assert spans.last_run()[0] is roots[-1]
-    kept = [spans.run_of(r) for r in roots]
-    assert kept[:3] == [None] * 3
-    assert all(run is not None and run[0] is r for run, r in zip(kept[3:], roots[3:]))
+    assert list(spans._finished) == [r.finished for r in roots[-spans.KEEP_RUNS:]]
 
 
 def test_threads_record_runs_of_their_own():
@@ -80,22 +78,29 @@ def test_threads_record_runs_of_their_own():
         sys.setswitchinterval(old)
     assert not errors and not any(th.is_alive() for th in threads)
     assert len(roots) == 8 * 200 and len({r.run for r in roots}) == len(roots)
-    kept = [run for run in map(spans.run_of, roots) if run is not None]
-    assert len(kept) == spans.KEEP_RUNS
-    for run in kept:
-        assert [s.name for s in run] == ["root", "child"]
-        assert run[1].run == run[0].run and run[1].attrs["t"] == run[0].attrs["t"]
+    for r in roots:
+        run = r.finished
+        assert run[0] is r and [s.name for s in run] == ["root", "child"]
+        assert run[1].run == r.run and run[1].attrs["t"] == r.attrs["t"]
+    kept = list(spans._finished)
+    assert len(kept) == spans.KEEP_RUNS and all(run[0] in roots for run in kept)
 
 
-def test_spanned_gives_a_root_result_its_run():
+def test_attach_run_gives_a_root_result_its_run():
     class Out:
         spans = None
 
-    f = spans.spanned("outer")(lambda: Out())
-    out = f()
+    def call():
+        out = Out()
+        with spans.span("outer") as s:
+            pass
+        spans.attach_run(s, out)
+        return out
+
+    out = call()
     assert [s.name for s in out.spans] == ["outer"]
     with spans.span("caller"):
-        nested = f()
+        nested = call()
     assert nested.spans is None  # not a root: the caller's run holds it
 
 
@@ -158,6 +163,35 @@ def test_profile_and_straggler_block_times_are_their_spans():
         blocks = [s for s in run if s.name == "bc.driver.block"]
         assert result.block_times == [b.seconds for b in blocks] and blocks
         assert [b.attrs["block"] for b in blocks] == list(range(1, len(blocks) + 1))
+
+
+def test_a_lost_dispatch_keeps_a_block_number_of_its_own():
+    from repro.distributed.fault_tolerance import ReplicaLostError
+
+    g = gnp_graph(12, 0.3, seed=2)
+    schedule, prep, _, _ = build_schedule(g, batch_size=2)
+    op = engine.make_dense_operator(jnp.asarray(g.dense_adjacency(np.float32)))
+    omega = jnp.zeros(g.n, jnp.float32)
+    base = jax.jit(lambda s, d: traversal_round(op, s, d, omega))
+    calls = []
+
+    def lanes(sources, derived):
+        calls.append(len(calls) + 1)
+        if len(calls) == 2:
+            raise ReplicaLostError(1)
+        outs = [base(sources[r], derived[r]) for r in range(sources.shape[0])]
+        return tuple(jnp.stack([o[i] for o in outs]) for i in range(4))
+
+    result = BCDriver(lanes, schedule, n=g.n, prep=prep, rounds_per_dispatch=2,
+                      straggler="steal").run()
+    run = result.spans
+    blocks = [s for s in run if s.name == "bc.driver.block"]
+    numbers = [b.attrs["block"] for b in blocks]
+    assert numbers == list(range(1, len(calls) + 1)) and len(blocks) >= 3
+    drained = {s.attrs["block"] for s in run if s.name == "bc.driver.drain"}
+    assert drained == set(numbers) - {2}  # the lost dispatch has no drain
+    assert result.block_times == [b.seconds for b in blocks if b.attrs["block"] in drained]
+    np.testing.assert_allclose(result.bc, brandes_reference(g), rtol=1e-5, atol=1e-5)
 
 
 def test_spans_share_the_profilers_clock(tmp_path):
