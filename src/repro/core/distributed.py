@@ -1138,7 +1138,9 @@ def distributed_betweenness_centrality(
     mid-round.
     Each call records a run of host spans (:mod:`repro.core.spans`;
     ``BCResult.spans``): the root ``bc.entry``, set-up
-    ``bc.setup.schedule`` (sampling plan, schedule), ``.partition``
+    ``bc.setup.schedule`` (sampling plan, schedule; attrs
+    ``derived_per_round`` and ``width``, the backward state's columns
+    ``batch_size + derived_per_round``), ``.partition``
     (2-D partition, autotune when on, tile counts, overlap and memory
     resolution), ``.layout`` (the host operand layout) and ``.transfer``
     (the puts until the operands are resident), then the driver's.
@@ -1276,7 +1278,7 @@ def distributed_betweenness_centrality(
                 raise ValueError(f"delta must be positive and finite, got {delta}")
         elif delta is not None:
             raise ValueError("delta is only meaningful with weighted=True")
-        with span("bc.setup.schedule"):
+        with span("bc.setup.schedule") as schedule_span:
             sample_plan = plan_sampling(
                 eligible_roots(graph), sampling, sample_frac, sample_k, sample_seed
             )
@@ -1298,6 +1300,8 @@ def distributed_betweenness_centrality(
                 root_order="eccentricity" if autotune != "off" else "id",
                 roots=sample_plan.roots,
             )
+            k = schedule.derived_per_round
+            schedule_span.annotate(derived_per_round=k, width=schedule.batch_size + k)
         with span("bc.setup.partition"):
             R, C, fr = _grid_axes(mesh, row_axis, col_axis, replica_axis)
             part = partition_2d(residual, R, C)

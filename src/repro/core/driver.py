@@ -150,6 +150,25 @@ def normalize_straggler(policy: str | None) -> str:
     return policy
 
 
+def _append_derived(sigma, depth, sources, derived, row_ids):
+    """The backward state and the root of every column: the explicit
+    columns, then the 2-degree columns derived from them (Alg. 7).
+
+    ``derived`` of k = 0 rows (a static shape: the schedule claimed no
+    2-degree vertex) leaves the forward state as it is, ``[n, s]`` wide.
+    """
+    if derived.shape[0] == 0:
+        return sigma, depth, sources
+    sigma_c, depth_c = derive_two_degree_columns(
+        sigma, depth, derived, row_ids=row_ids
+    )
+    return (
+        jnp.concatenate([sigma, sigma_c], axis=1),
+        jnp.concatenate([depth, depth_c], axis=1),
+        jnp.concatenate([sources, derived[:, 0]]),
+    )
+
+
 def traversal_round(
     operator: TraversalOperator,
     sources: jnp.ndarray,  # i32 [s]; -1 = padding
@@ -201,11 +220,9 @@ def traversal_round(
     )
 
     # ------------------------------------------- derived 2-degree columns
-    sigma_c, depth_c = derive_two_degree_columns(
-        fwd.sigma, fwd.depth, derived, row_ids=row_ids
+    sigma_all, depth_all, roots = _append_derived(
+        fwd.sigma, fwd.depth, sources, derived, row_ids
     )
-    sigma_all = jnp.concatenate([fwd.sigma, sigma_c], axis=1)
-    depth_all = jnp.concatenate([fwd.depth, depth_c], axis=1)
 
     # ---------------------------------------------------------- backward
     # decomposed max: grid first (the per-replica depth = the straggler
@@ -225,7 +242,6 @@ def traversal_round(
     delta, bwd_err = bwd if checksum else (bwd, None)
 
     # --------------------------------------------------------- BC + n_s
-    roots = jnp.concatenate([sources, derived[:, 0]])
     omega_root = op.root_omega(roots, omega_f)
     mult = jnp.where(roots >= 0, omega_root + 1.0, 0.0)
 
@@ -267,8 +283,8 @@ def _weighted_round(
     The round's ``levels`` slot carries the bucket count (the same
     data-dependent cost signal the straggler scheduler consumes).  The
     2-degree derivation is level-based and is rejected upstream for
-    weighted runs, so ``derived`` is always all-padding here — the
-    derived columns stay shape-compatible and inert.  ``num_levels``
+    weighted runs, so ``derived`` is empty (k = 0) or all-padding here —
+    padding columns stay shape-compatible and inert.  ``num_levels``
     (the static-trip-count dry-run mode) has no weighted analogue: the
     bucket loop's trip count is data-dependent by construction.
     """
@@ -298,18 +314,15 @@ def _weighted_round(
 
     # derived columns: always padding under weighted (h2/h3 rejected
     # upstream) — kept for shape compatibility with the driver contract
-    sigma_c, depth_c = derive_two_degree_columns(
-        fwd.sigma, bucket, derived, row_ids=row_ids
+    _, bucket_all, roots = _append_derived(
+        fwd.sigma, bucket, sources, derived, row_ids
     )
-    sigma_all = jnp.concatenate([fwd.sigma, sigma_c], axis=1)
-    bucket_all = jnp.concatenate([bucket, depth_c], axis=1)
 
     grid_max = op.reduce_max_grid(jnp.max(bucket_all))
     max_bucket = op.reduce_max_sync(grid_max)
     delta_acc = engine.backward_buckets(op, fwd.sigma, fwd.dist, omega_f, max_bucket)
-    delta_all = jnp.concatenate([delta_acc, jnp.zeros_like(sigma_c)], axis=1)
+    delta_all = jnp.pad(delta_acc, ((0, 0), (0, derived.shape[0])))
 
-    roots = jnp.concatenate([sources, derived[:, 0]])
     omega_root = op.root_omega(roots, omega_f)
     mult = jnp.where(roots >= 0, omega_root + 1.0, 0.0)
 

@@ -6,7 +6,9 @@ unit of jit compilation, checkpointing, straggler re-execution and
 sub-cluster distribution):
 
 * every round holds ``batch_size`` explicit sources (padded with -1) and
-  up to ``derived_per_round`` 2-degree derived columns (c, a_pos, b_pos);
+  up to ``derived_per_round`` 2-degree derived columns (c, a_pos, b_pos),
+  a width sized from the claims: a schedule that claims no 2-degree
+  vertex carries none, so its backward state is ``batch_size`` wide;
 * a derived vertex's two neighbors must be explicit sources *of the same
   round* (their forward columns feed Alg. 7); the packer keeps triples
   intact and demotes a triple to an explicit source on conflict —
@@ -221,7 +223,9 @@ def build_schedule(
                   contract whole pendant trees (beyond-paper exhaustive
                   1-degree pass).
       derived_per_round: cap on derived columns per round (default:
-                  batch_size // 2 — a triple contributes ≥2 sources).
+                  ``min(batch_size // 2, claimed triples)`` — a triple
+                  contributes ≥2 sources, and a schedule with no claim,
+                  as under "h0"/"h1", derives nothing: k = 0).
       root_order: one of :data:`ROOT_ORDERS` — "id" (legacy vertex-id
                   fill) or "eccentricity" (sampled-eccentricity
                   descending, packing similar-depth roots into the same
@@ -262,8 +266,6 @@ def build_schedule(
     use_h1 = heuristics in ("h1", "h3", "h1t", "h3t")
     use_h2 = heuristics in ("h2", "h3", "h3t")
     exhaustive = heuristics.endswith("t")  # beyond-paper tree contraction
-    if derived_per_round is None:
-        derived_per_round = max(1, batch_size // 2)
 
     prep = one_degree_reduce(graph, exhaustive=exhaustive) if use_h1 else None
     residual = prep.residual if prep is not None else graph
@@ -298,6 +300,8 @@ def build_schedule(
         adj = residual.adjacency_lists()
         triples = claim_two_degree(res_deg, adj, eligible)
     derived_set = {c for c, _, _ in triples}
+    if derived_per_round is None:
+        derived_per_round = min(batch_size // 2, len(triples))
 
     rounds: list[Round] = []
     cur_src: list[int] = []
@@ -314,8 +318,8 @@ def build_schedule(
 
     # 1) place triples (sorted so shared-neighbor triples cluster)
     for c, a, b in sorted(triples, key=lambda t: (t[1], t[2])):
-        if batch_size < 2:
-            demoted.append(c)  # a triple needs two co-resident sources
+        if batch_size < 2 or derived_per_round < 1:
+            demoted.append(c)  # a triple needs two co-resident sources and a slot
             continue
         if a in consumed and a not in cur_pos or b in consumed and b not in cur_pos:
             demoted.append(c)  # neighbor already ran in a closed round

@@ -91,6 +91,12 @@ class Span:
         self.start_ns = time.time_ns()
         return self
 
+    def annotate(self, **attrs) -> None:
+        """Add attributes known only once the open span's work is done;
+        they reach the trace event too."""
+        self.attrs.update(attrs)
+        self._note.set_metadata(**attrs)
+
     def __exit__(self, *exc) -> None:
         self.end_ns = time.time_ns()
         self._note.__exit__(*exc)

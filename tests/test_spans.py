@@ -37,9 +37,10 @@ def test_nesting_parents_and_run_ids():
     assert all(s.start_ns <= s.end_ns for s in run)
     assert run[0].start_ns <= run[1].start_ns <= run[2].end_ns <= run[1].end_ns
     assert root.finished is run and a.finished is None and c.finished is None
-    with spans.span("next") as nxt:
-        pass
+    with spans.span("next", k=1) as nxt:
+        nxt.annotate(width=8)
     assert nxt.run > root.run and spans.last_run()[0] is nxt
+    assert nxt.attrs == {"k": 1, "width": 8}
 
 
 def test_only_the_newest_runs_are_kept():
@@ -140,6 +141,8 @@ def test_entry_records_its_spans_in_order():
         assert all(c.attrs.get("block", b.attrs["block"]) == b.attrs["block"] for c in children)
         assert b.start_ns <= children[0].start_ns and children[-1].end_ns <= b.end_ns
     assert run[names.index("bc.setup.transfer")].attrs["bytes"] > 0
+    # h0 claims no 2-degree vertex: the backward state is the batch wide
+    assert run[names.index("bc.setup.schedule")].attrs == {"derived_per_round": 0, "width": 8}
     # the result's timings are the spans' durations
     assert result.wall_s == run[driver].seconds
     assert result.block_times == [b.seconds for b in blocks]
@@ -204,6 +207,7 @@ def test_spans_share_the_profilers_clock(tmp_path):
     data = ProfileData.from_file(path)
     start = None
     events = []
+    schedule_stats = []
     for plane in data.planes:
         if plane.name == "Task Environment":
             start = dict(plane.stats)["profile_start_time"]
@@ -211,7 +215,12 @@ def test_spans_share_the_profilers_clock(tmp_path):
             for ev in line.events:
                 if ev.name.startswith("bc."):
                     events.append((ev.name, dict(ev.stats).get("block"), ev.start_ns))
+                if ev.name == "bc.setup.schedule":
+                    schedule_stats.append(dict(ev.stats))
     assert start is not None
+    # attributes annotated after the span opened reach the trace event
+    (stats,) = schedule_stats
+    assert int(stats["derived_per_round"]) == 0 and int(stats["width"]) == 8
     events.sort(key=lambda e: e[2])
     recorded = [(s.name, s.attrs.get("block"), s.start_ns) for s in result.spans]
     assert [e[:2] for e in events] == [r[:2] for r in recorded]

@@ -185,6 +185,7 @@ def test_straggler_kill_and_resume(tmp_path, resume_policy):
     no matter which lane would execute it after the resume)."""
     g = skewed_depth_graph(4, 8)
     schedule, prep, _, _ = build_schedule(g, batch_size=8)
+    assert schedule.derived_per_round == 0  # h0 derives nothing: [fr, 0, 3] blocks
     n_rounds = len(schedule.rounds)
     expected = brandes_reference(g)
     ckpt = BCCheckpoint(str(tmp_path / "bc.npz"))

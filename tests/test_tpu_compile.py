@@ -155,3 +155,29 @@ def test_2x2_pallas_sparse_expand_fold_round_compiles(topo):
         _sds((1, 0, 3), jnp.int32, rep),
     )
     _assert_kernel(lowered)
+
+
+@pytest.mark.parametrize("k", [0, S // 2], ids=["k0", "k64"])
+def test_1x1_pallas_sparse_round_compiles(topo, k):
+    """The one-chip cell's round: R-MAT SCALE 15 on a 1x1 mesh, with no
+    derived columns (a schedule that claims nothing) or half a batch."""
+    from repro.core.distributed import make_distributed_round_fn
+    from repro.graphs.partition import partition_arcs_2d
+
+    empty = np.zeros(0, np.int64)
+    part = partition_arcs_2d(empty, empty, N, 1, 1)  # geometry only
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    round_fn = make_distributed_round_fn(
+        part, mesh, engine_kind="pallas_sparse", interpret=False
+    )
+    grid = NamedSharding(mesh, P("data", "model"))
+    rep = NamedSharding(mesh, P())
+    lowered = round_fn.lower(
+        _sds((1, 1, TILES, 128, 128), jnp.float32, grid),
+        _sds((1, 1, TILES), jnp.int32, grid),
+        _sds((1, 1, TILES), jnp.int32, grid),
+        _sds((part.n_pad,), jnp.float32, NamedSharding(mesh, P(("model", "data")))),
+        _sds((1, S), jnp.int32, rep),
+        _sds((1, k, 3), jnp.int32, rep),
+    )
+    _assert_kernel(lowered)
