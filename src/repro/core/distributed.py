@@ -85,10 +85,12 @@ from repro.core.spans import attach_run, span
 from repro.graphs.graph import Graph
 from repro.graphs.partition import TwoDPartition, partition_2d
 from repro.roofline.model import (
+    adjacency_stream_bytes,
     auto_overlap_policy,
     cell_kernel_choice,
     device_hardware,
     device_hbm_footprint,
+    exchange_operands,
     sparse_tile_bytes,
 )
 
@@ -103,6 +105,7 @@ __all__ = [
     "resolve_overlap",
     "hybrid_cell_choice",
     "level_time_estimates",
+    "exchange_bytes",
     "prior_round_seconds",
     "weighted_prior_levels",
     "estimate_device_footprint",
@@ -326,6 +329,9 @@ def estimate_device_footprint(
     cell only *streams* its chosen one.  For the arc-list engine under
     a ring policy it is the 2·R·max_ring_arcs ring layout
     (:meth:`TwoDPartition.ring_arcs_max`), not the flat arc arrays.
+    Under a ring policy the blocked-sparse engine also holds
+    ``ring_copy_bytes``: the ring steps' slices of the tile list, which
+    its compiled round copies (in ``total_bytes``; 0 for the others).
     ``bm``/``bk`` override the default tile shape; pass the same
     ``tile`` the engine will be built with.
     """
@@ -357,7 +363,7 @@ def estimate_device_footprint(
         if ring:
             max_arcs = partition.R * partition.ring_arcs_max()
         kw = dict(max_arcs=max_arcs)
-    return device_hbm_footprint(
+    foot = device_hbm_footprint(
         engine_kind,
         R=partition.R,
         C=partition.C,
@@ -365,6 +371,12 @@ def estimate_device_footprint(
         batch_size=batch_size,
         **kw,
     )
+    # a ring step's tiles are a dynamic slice of the resident ring tile
+    # list, and the compiled round materializes the R slices of a level
+    copy = foot["adjacency_bytes"] if ring and engine_kind == "pallas_sparse" else 0.0
+    foot["ring_copy_bytes"] = copy
+    foot["total_bytes"] += copy
+    return foot
 
 
 def check_device_memory(
@@ -390,10 +402,11 @@ def check_device_memory(
         dense_cells=dense_cells,
     )
     logger.info(
-        "per-device HBM footprint (%s): adjacency %.3f GiB + state %.3f GiB "
-        "= %.3f GiB%s",
+        "per-device HBM footprint (%s): adjacency %.3f GiB + ring copies %.3f GiB "
+        "+ state %.3f GiB = %.3f GiB%s",
         engine_kind,
         foot["adjacency_bytes"] / 2**30,
+        foot["ring_copy_bytes"] / 2**30,
         foot["state_bytes"] / 2**30,
         foot["total_bytes"] / 2**30,
         ""
@@ -418,7 +431,8 @@ def check_device_memory(
         raise MemoryError(
             f"engine_kind={engine_kind!r} needs "
             f"{foot['total_bytes']/2**30:.2f} GiB/device "
-            f"(adjacency {foot['adjacency_bytes']/2**30:.2f} GiB + state "
+            f"(adjacency {foot['adjacency_bytes']/2**30:.2f} GiB + ring copies "
+            f"{foot['ring_copy_bytes']/2**30:.2f} GiB + state "
             f"{foot['state_bytes']/2**30:.2f} GiB) but the HBM budget is "
             f"{hbm_limit_bytes/2**30:.2f} GiB; try " + " or ".join(suggestions)
         )
@@ -450,8 +464,6 @@ def level_time_estimates(
     (:func:`repro.roofline.model.device_hardware`).
     """
     R, C, chunk, s = partition.R, partition.C, partition.chunk, batch_size
-    from repro.roofline.model import adjacency_stream_bytes
-
     hw = device_hardware() if hw is None else hw
 
     if engine_kind in ("pallas", "pallas_bf16"):
@@ -491,12 +503,37 @@ def level_time_estimates(
         )
     if engine_kind != "pallas_hybrid":
         compute_s = max(flops / hw.peak_bf16_flops, a_bytes / hw.hbm_bandwidth)
-    from repro.roofline.model import exchange_operands
+    expand_b, fold_b = _exchange_parts(partition, engine_kind, s)[0]  # forward
+    return compute_s, expand_b / hw.ici_link_bandwidth, fold_b / hw.ici_link_bandwidth
 
-    n_operands = exchange_operands(engine_kind)[0]  # forward exchange set
-    expand_s = (R - 1) * chunk * s * 4 * n_operands / hw.ici_link_bandwidth
-    fold_s = (C - 1) / C * (C * chunk) * s * 4 / hw.ici_link_bandwidth
-    return compute_s, expand_s, fold_s
+
+def _exchange_parts(partition: TwoDPartition, engine_kind: str, width: int):
+    """((expand, fold) forward, (expand, fold) backward) bytes one chip
+    sends a level: R-1 hops of its owned exchange set
+    (:func:`repro.roofline.model.exchange_operands`, ``width`` columns a
+    block) and C-1 f32 [chunk, width] blocks of the fold's partial."""
+    R, C, chunk = partition.R, partition.C, partition.chunk
+    fold = (C - 1) * chunk * width * 4
+    return tuple(
+        ((R - 1) * chunk * (blocks * width + vectors) * 4, fold)
+        for blocks, vectors in exchange_operands(engine_kind)
+    )
+
+
+def exchange_bytes(partition: TwoDPartition, engine_kind: str, width: int) -> tuple[int, int]:
+    """Bytes one chip sends per (forward, backward) level of an unweighted
+    round through the expand and the fold, from the shapes and dtypes the
+    level ships: ``width`` state columns (batch + derived, plus the
+    checksum lane under ``integrity="checksum"``) of the chip's owned
+    chunk, all 4-byte (σ, δ f32, d i32, ω f32 [chunk]).
+
+    Every overlap policy sends the same bytes: R-1 ring hops of the owned
+    operands or a ring all_gather of them, C-1 reduce-ring hops or a
+    psum_scatter; the policy decides only what the transfers overlap.
+    0 on a 1x1 mesh.
+    """
+    fwd, bwd = _exchange_parts(partition, engine_kind, width)
+    return sum(fwd), sum(bwd)
 
 
 #: Nominal level count pricing the straggler prior: forward + backward
@@ -1302,7 +1339,7 @@ def distributed_betweenness_centrality(
             )
             k = schedule.derived_per_round
             schedule_span.annotate(derived_per_round=k, width=schedule.batch_size + k)
-        with span("bc.setup.partition"):
+        with span("bc.setup.partition") as partition_span:
             R, C, fr = _grid_axes(mesh, row_axis, col_axis, replica_axis)
             part = partition_2d(residual, R, C)
 
@@ -1365,6 +1402,19 @@ def distributed_betweenness_centrality(
                 bm=bm, bk=bk, overlap="none" if weighted else overlap,
                 tile_counts=tile_counts, dense_cells=dense_cells,
             )
+            # what a level runs and ships per chip; weighted rounds run
+            # barrier collectives once per bucket relaxation, not per level
+            ring = overlap != "none" and not weighted
+            partition_span.annotate(
+                mesh=f"{R}x{C}", overlap=overlap, chunk=part.chunk,
+                ring_steps=R if ring else 1,
+            )
+            if not weighted:
+                lanes = schedule.batch_size + k + (integrity == "checksum")
+                fwd_bytes, bwd_bytes = exchange_bytes(part, engine_kind, lanes)
+                partition_span.annotate(
+                    exchange_bytes_fwd=fwd_bytes, exchange_bytes_bwd=bwd_bytes
+                )
 
         round_fn = make_distributed_round_fn(
             part,
@@ -1382,13 +1432,18 @@ def distributed_betweenness_centrality(
 
         from jax.sharding import NamedSharding
 
-        with span("bc.setup.layout"):
+        with span("bc.setup.layout") as layout_span:
             omega_pad = np.zeros(part.n_pad, np.float32)
             omega_pad[: graph.n] = omega_i
             host_args = graph_host_arrays(
                 part, engine_kind, overlap, tile=tile, dense_cells=dense_cells,
                 weights=residual.w if weighted else None,
             )
+            if engine_kind == "pallas_sparse":
+                layout_span.annotate(
+                    tile_slots=tile_counts["stored_tiles_ring" if ring else "stored_tiles_full"],
+                    tiles_stored=tile_counts["stored_tiles_full"],
+                )
         nbytes = omega_pad.nbytes + sum(a.nbytes for a in host_args)
         with span("bc.setup.transfer", bytes=nbytes):
             # reorder omega into chunk-owner layout: flat position = chunk-id*chunk + off

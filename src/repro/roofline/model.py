@@ -214,16 +214,17 @@ def sampled_run_seconds(num_rounds: int, fr: int, round_s: float) -> float:
 # both the memory guard and the sparse benchmark record.
 # ---------------------------------------------------------------------------
 
-#: payload tensors per exchanged direction: the arc-list engine ships a
-#: single pre-masked tensor; the fused Pallas engines (dense-block,
-#: blocked-sparse, and the per-cell hybrid of the two) ship (σ, d)
-#: forward and (σ, d, δ, ω) backward (paper §3.2 exchange set).
+#: payload per exchanged direction, as ([chunk, s] state blocks, [chunk]
+#: vectors): the arc-list engine ships a single pre-masked block; the
+#: fused Pallas engines (dense-block, blocked-sparse, and the per-cell
+#: hybrid of the two) ship (σ, d) forward and (σ, d, δ) + ω backward
+#: (paper §3.2 exchange set).
 _EXCHANGE_OPERANDS = {
-    "sparse": (1, 1),
-    "pallas": (2, 4),
-    "pallas_bf16": (2, 4),
-    "pallas_sparse": (2, 4),
-    "pallas_hybrid": (2, 4),
+    "sparse": ((1, 0), (1, 0)),
+    "pallas": ((2, 0), (3, 1)),
+    "pallas_bf16": ((2, 0), (3, 1)),
+    "pallas_sparse": ((2, 0), (3, 1)),
+    "pallas_hybrid": ((2, 0), (3, 1)),
 }
 
 #: per-stored-tile scalar-prefetch/grid-step overhead allowance of the
@@ -295,14 +296,15 @@ def cell_kernel_choice(
     return bcsr_bytes >= threshold * dense_bytes
 
 
-def exchange_operands(engine_kind: str) -> tuple[int, int]:
-    """(forward, backward) per-level exchange-operand counts of an engine.
+def exchange_operands(engine_kind: str) -> tuple[tuple[int, int], tuple[int, int]]:
+    """(forward, backward) per-level exchange set of an engine, each as
+    ([chunk, s] state blocks, [chunk] vectors).
 
     The single source of the §3.2 exchange-set table above: the arc-list
-    engine gathers one pre-masked tensor per direction; the fused-kernel
-    engines exchange (σ, d) forward and (σ, d, δ, ω) backward.  Consumed
-    by the state-footprint model here and the per-level collective
-    pricing in :func:`repro.core.distributed.level_time_estimates`.
+    engine gathers one pre-masked block per direction; the fused-kernel
+    engines exchange (σ, d) forward and (σ, d, δ) + ω backward.  Consumed
+    by the state-footprint model here and the per-level exchange bytes
+    in :func:`repro.core.distributed.exchange_bytes`.
     """
     return _EXCHANGE_OPERANDS[engine_kind]
 
@@ -390,11 +392,11 @@ def device_hbm_footprint(
         bk=bk,
         max_arcs=max_arcs,
     )
-    _, bwd_operands = _EXCHANGE_OPERANDS[engine_kind]
+    _, (blocks, vectors) = _EXCHANGE_OPERANDS[engine_kind]
     state = (
         3 * chunk * s * 4  # owned σ, d, δ
         + 2 * chunk * 4  # ω, bc accumulator
-        + bwd_operands * R * chunk * s * 4  # gathered operand slice (worst: bwd)
+        + (blocks * s + vectors) * R * chunk * 4  # gathered operand slice (worst: bwd)
         + C * chunk * s * 4  # pre-fold partial
     )
     return {

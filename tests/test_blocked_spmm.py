@@ -150,6 +150,21 @@ def test_footprint_prices_ring_layouts():
     assert got["adjacency_bytes"] == want
 
 
+@pytest.mark.parametrize("kind", ["sparse", "pallas", "pallas_sparse", "pallas_hybrid"])
+@pytest.mark.parametrize("overlap", ["none", "expand+fold"])
+def test_footprint_prices_ring_slot_copies_of_the_tile_list_only(kind, overlap):
+    """Only the blocked-sparse ring round is known to copy its ring steps'
+    tile slices (``tests/test_tpu_compile.py`` reads the compiled round):
+    the guard adds the ring tile list once more there, and nothing for the
+    other engines or the barrier schedule."""
+    part = partition_2d(rmat_graph(10, 4, seed=1), 2, 2)
+    foot = estimate_device_footprint(part, kind, 16, bm=8, bk=8, overlap=overlap)
+    copied = kind == "pallas_sparse" and overlap != "none"
+    assert foot["ring_copy_bytes"] == (foot["adjacency_bytes"] if copied else 0.0)
+    assert foot["total_bytes"] == pytest.approx(
+        foot["adjacency_bytes"] + foot["ring_copy_bytes"] + foot["state_bytes"])
+
+
 # ---------------------------------------------------------------- kernels
 @pytest.mark.parametrize("use_pallas", [True, False])
 def test_sparse_kernels_match_dense_partials(rng, use_pallas):

@@ -15,6 +15,8 @@ sees the CPU here, so every kernel is given ``interpret=False``.
 from __future__ import annotations
 
 import functools
+import math
+import re
 
 import numpy as np
 import pytest
@@ -132,13 +134,28 @@ def test_single_device_pallas_round_compiles(one_chip):
     _assert_kernel(lowered)
 
 
-def test_2x2_pallas_sparse_expand_fold_round_compiles(topo):
+#: the sends of a compiled program: ``%name = (<operand shape>, ...)
+#: collective-permute-start(...)``
+PERMUTE_START = re.compile(
+    r"^\s*%\S+ = \((\w+)\[([\d,]*)\][^ ]*, .*? collective-permute-start\(", re.M)
+
+
+#: the four-chip cell, R-MAT SCALE 16 on a 2x2 mesh: vertices, and the
+#: stored tiles of its fullest ring slot
+N_2X2, SLOT_2X2 = 65536, 32187
+
+
+@pytest.fixture(scope="module")
+def cell_2x2(topo):
+    """The four-chip cell's round under ``expand+fold``, ``h0`` (k = 0),
+    128 roots, chunk 16,384, compiled at the cell's tiles per ring slot:
+    (the geometry-only partition, the compiled program)."""
     from repro.core.distributed import make_distributed_round_fn
     from repro.graphs.partition import partition_arcs_2d
 
-    n, R, C = 65536, 2, 2  # the four-chip cell: R-MAT SCALE 16
+    R = C = 2
     empty = np.zeros(0, np.int64)
-    part = partition_arcs_2d(empty, empty, n, R, C)  # geometry only
+    part = partition_arcs_2d(empty, empty, N_2X2, R, C)  # geometry only
     mesh = Mesh(np.asarray(topo.devices).reshape(R, C), ("data", "model"))
     round_fn = make_distributed_round_fn(
         part, mesh, engine_kind="pallas_sparse", overlap="expand+fold",
@@ -147,14 +164,45 @@ def test_2x2_pallas_sparse_expand_fold_round_compiles(topo):
     grid = NamedSharding(mesh, P("data", "model"))
     rep = NamedSharding(mesh, P())
     lowered = round_fn.lower(
-        _sds((R, C, R, TILES, 128, 128), jnp.float32, grid),
-        _sds((R, C, R, TILES), jnp.int32, grid),
-        _sds((R, C, R, TILES), jnp.int32, grid),
+        _sds((R, C, R, SLOT_2X2, 128, 128), jnp.float32, grid),
+        _sds((R, C, R, SLOT_2X2), jnp.int32, grid),
+        _sds((R, C, R, SLOT_2X2), jnp.int32, grid),
         _sds((part.n_pad,), jnp.float32, NamedSharding(mesh, P(("model", "data")))),
         _sds((1, S), jnp.int32, rep),
         _sds((1, 0, 3), jnp.int32, rep),
     )
-    _assert_kernel(lowered)
+    return part, lowered.compile()
+
+
+def test_2x2_pallas_sparse_expand_fold_round_compiles(cell_2x2):
+    """The kernels are compiled, and the ring hops are the ones the entry's
+    ``exchange_bytes`` prices: (σ, d) and the fold forward, (σ, d, δ, ω)
+    and the fold backward."""
+    from repro.core.distributed import exchange_bytes
+
+    part, compiled = cell_2x2
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    sends = PERMUTE_START.findall(text)
+    assert len(sends) == 3 + 5
+    sent = sum(math.prod(map(int, dims.split(","))) * np.dtype(
+        {"f32": np.float32, "s32": np.int32}[dtype]).itemsize for dtype, dims in sends)
+    assert sent == sum(exchange_bytes(part, "pallas_sparse", S))
+
+
+def test_2x2_memory_guard_prices_the_compiled_ring_round(cell_2x2):
+    """Each ring step's tile slice is copied by the compiled round, so a
+    chip holds the ring tile list twice: the guard prices both."""
+    from repro.core.distributed import estimate_device_footprint
+
+    part, compiled = cell_2x2
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    counts = {"stored_tiles_ring": 2 * SLOT_2X2, "bm": 128, "bk": 128}
+    foot = estimate_device_footprint(
+        part, "pallas_sparse", S, overlap="expand+fold", tile_counts=counts)
+    assert foot["ring_copy_bytes"] == foot["adjacency_bytes"]
+    assert held <= foot["total_bytes"] <= 1.05 * held
 
 
 @pytest.mark.parametrize("k", [0, S // 2], ids=["k0", "k64"])

@@ -33,6 +33,8 @@ SHARDS: dict[str, tuple[str, ...]] = {
         "tests/test_dist_bc.py",
         "tests/test_dist_overlap.py",
         "tests/test_dist_gnn2d.py",
+        "tests/test_dist_exchange.py",
+        "tests/test_dist_derived_width.py",
     ),
     "dist-weighted": (  # ~97s
         "tests/test_weighted.py",
@@ -59,6 +61,7 @@ SHARDS: dict[str, tuple[str, ...]] = {
         "tests/test_sampling.py",
         "tests/test_bench_check.py",
         "tests/test_arch_smoke.py",
+        "tests/test_spans.py",
     ),
 }
 
