@@ -329,9 +329,6 @@ def estimate_device_footprint(
     cell only *streams* its chosen one.  For the arc-list engine under
     a ring policy it is the 2·R·max_ring_arcs ring layout
     (:meth:`TwoDPartition.ring_arcs_max`), not the flat arc arrays.
-    Under a ring policy the blocked-sparse engine also holds
-    ``ring_copy_bytes``: the ring steps' slices of the tile list, which
-    its compiled round copies (in ``total_bytes``; 0 for the others).
     ``bm``/``bk`` override the default tile shape; pass the same
     ``tile`` the engine will be built with.
     """
@@ -363,7 +360,7 @@ def estimate_device_footprint(
         if ring:
             max_arcs = partition.R * partition.ring_arcs_max()
         kw = dict(max_arcs=max_arcs)
-    foot = device_hbm_footprint(
+    return device_hbm_footprint(
         engine_kind,
         R=partition.R,
         C=partition.C,
@@ -371,12 +368,6 @@ def estimate_device_footprint(
         batch_size=batch_size,
         **kw,
     )
-    # a ring step's tiles are a dynamic slice of the resident ring tile
-    # list, and the compiled round materializes the R slices of a level
-    copy = foot["adjacency_bytes"] if ring and engine_kind == "pallas_sparse" else 0.0
-    foot["ring_copy_bytes"] = copy
-    foot["total_bytes"] += copy
-    return foot
 
 
 def check_device_memory(
@@ -402,11 +393,10 @@ def check_device_memory(
         dense_cells=dense_cells,
     )
     logger.info(
-        "per-device HBM footprint (%s): adjacency %.3f GiB + ring copies %.3f GiB "
-        "+ state %.3f GiB = %.3f GiB%s",
+        "per-device HBM footprint (%s): adjacency %.3f GiB + state %.3f GiB "
+        "= %.3f GiB%s",
         engine_kind,
         foot["adjacency_bytes"] / 2**30,
-        foot["ring_copy_bytes"] / 2**30,
         foot["state_bytes"] / 2**30,
         foot["total_bytes"] / 2**30,
         ""
@@ -431,8 +421,7 @@ def check_device_memory(
         raise MemoryError(
             f"engine_kind={engine_kind!r} needs "
             f"{foot['total_bytes']/2**30:.2f} GiB/device "
-            f"(adjacency {foot['adjacency_bytes']/2**30:.2f} GiB + ring copies "
-            f"{foot['ring_copy_bytes']/2**30:.2f} GiB + state "
+            f"(adjacency {foot['adjacency_bytes']/2**30:.2f} GiB + state "
             f"{foot['state_bytes']/2**30:.2f} GiB) but the HBM budget is "
             f"{hbm_limit_bytes/2**30:.2f} GiB; try " + " or ".join(suggestions)
         )

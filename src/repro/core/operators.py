@@ -877,10 +877,14 @@ class DistributedPallasSparseOperator(DistributedPallasOperator):
 
     Under a ring overlap policy the per-ring-chunk tile slices
     (``ring_*`` [R, Tr, ...]; slot r = the tiles sourced in the chunk of
-    grid row r, column ids re-based to the chunk) are selected by
-    ``dynamic_index_in_dim`` at each hop — the exact sparse counterpart
-    of the dense engine's ``dynamic_slice`` — and the chunked-``acc``
-    kernel mode carries the running partial between hops.
+    grid row r, column ids re-based to the chunk) are selected at each
+    hop: the slot's row/col ids by ``dynamic_index_in_dim``, its tiles by
+    the kernel's tile index map, which reads them from the resident ring
+    tiles (viewed as [R·Tr, bm, bk]) at the scalar-prefetched offset
+    r·Tr — a dynamic slice of the tiles would be a copy of the slot in
+    front of every call, since the kernel takes whole buffers.  The
+    chunked-``acc`` kernel mode carries the running partial between hops.
+    A block is (tiles, tile_rows, tile_cols, tile_offset).
     """
 
     def __init__(
@@ -928,40 +932,42 @@ class DistributedPallasSparseOperator(DistributedPallasOperator):
 
     # ------------------------------------------------------ block hooks
     def _full_block(self):
-        return (self.tiles, self.tile_rows, self.tile_cols)
+        return (self.tiles, self.tile_rows, self.tile_cols, 0)
 
     def _ring_block(self, r):
         pick = lambda a: jax.lax.dynamic_index_in_dim(a, r, keepdims=False)
+        R, slot, bm, bk = self.ring_tiles.shape
         return (
-            pick(self.ring_tiles),
+            self.ring_tiles.reshape(R * slot, bm, bk),
             pick(self.ring_tile_rows),
             pick(self.ring_tile_cols),
+            r * slot,
         )
 
     def _partial_forward(self, block, sigma, depth, lvl, acc=None):
         from repro.kernels import ops as kops
 
-        tiles, rows, cols = block
+        tiles, rows, cols, off = block
         return kops.frontier_spmm_sparse(
-            tiles, rows, cols, sigma, depth, lvl,
-            m=self.C * self.chunk, acc=acc, interpret=self.interpret,
+            tiles, rows, cols, sigma, depth, lvl, m=self.C * self.chunk,
+            acc=acc, tile_offset=off, interpret=self.interpret,
         )
 
     def _partial_backward(self, block, sigma, depth, delta, omega, lvl, acc=None):
         from repro.kernels import ops as kops
 
-        tiles, rows, cols = block
+        tiles, rows, cols, off = block
         return kops.dependency_spmm_sparse(
-            tiles, rows, cols, sigma, depth, delta, omega, lvl,
-            m=self.C * self.chunk, acc=acc, interpret=self.interpret,
+            tiles, rows, cols, sigma, depth, delta, omega, lvl, m=self.C * self.chunk,
+            acc=acc, tile_offset=off, interpret=self.interpret,
         )
 
     # --------------------------------------- reference apply() semantics
     def _dense_of(self, block, kdim):
         from repro.kernels.blocked_spmm import tiles_to_dense
 
-        tiles, rows, cols = block
-        return tiles_to_dense(tiles, rows, cols, self.C * self.chunk, kdim)
+        tiles, rows, cols, off = block
+        return tiles_to_dense(tiles, rows, cols, self.C * self.chunk, kdim, off)
 
     def _local(self, x_col):
         # parity/debug path only — the engine runs the fused level hooks
@@ -1017,22 +1023,22 @@ class DistributedPallasHybridOperator(DistributedPallasSparseOperator):
     def _partial_forward(self, block, sigma, depth, lvl, acc=None):
         from repro.kernels import ops as kops
 
-        a_dense, tiles, rows, cols = block
+        a_dense, tiles, rows, cols, off = block
         return jax.lax.cond(
             self.dense_cell,
             lambda: kops.frontier_spmm_partial(
                 a_dense, sigma, depth, lvl, acc=acc, interpret=self.interpret
             ),
             lambda: kops.frontier_spmm_sparse(
-                tiles, rows, cols, sigma, depth, lvl,
-                m=self.C * self.chunk, acc=acc, interpret=self.interpret,
+                tiles, rows, cols, sigma, depth, lvl, m=self.C * self.chunk,
+                acc=acc, tile_offset=off, interpret=self.interpret,
             ),
         )
 
     def _partial_backward(self, block, sigma, depth, delta, omega, lvl, acc=None):
         from repro.kernels import ops as kops
 
-        a_dense, tiles, rows, cols = block
+        a_dense, tiles, rows, cols, off = block
         return jax.lax.cond(
             self.dense_cell,
             lambda: kops.dependency_spmm_partial(
@@ -1040,8 +1046,8 @@ class DistributedPallasHybridOperator(DistributedPallasSparseOperator):
                 acc=acc, interpret=self.interpret,
             ),
             lambda: kops.dependency_spmm_sparse(
-                tiles, rows, cols, sigma, depth, delta, omega, lvl,
-                m=self.C * self.chunk, acc=acc, interpret=self.interpret,
+                tiles, rows, cols, sigma, depth, delta, omega, lvl, m=self.C * self.chunk,
+                acc=acc, tile_offset=off, interpret=self.interpret,
             ),
         )
 

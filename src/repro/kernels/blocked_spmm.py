@@ -14,11 +14,15 @@ Grid = (s/bs, T) with the tile index minor.  The tile row/col ids are
 **scalar-prefetched** (``pltpu.PrefetchScalarGridSpec``): the BlockSpec
 index maps read them to DMA the right operand tile ([tile_cols[t]·bk
 rows of the gathered operands]) and output tile ([tile_rows[t]·bm rows
-of the partial product]) ahead of the kernel body.  Tiles arrive sorted
-by output tile-row, so each tile-row is one consecutive run of grid
-steps: the f32 VMEM accumulator initializes at the run's first tile
-(from zeros, or from the carried ring accumulator in ``acc`` mode) and
-flushes to the output block at the run's last tile.  The layout
+of the partial product]) ahead of the kernel body.  A prefetched
+``tile_offset`` places the call's T tiles in a longer stored array
+(tile t is ``tiles[tile_offset + t]``), so a ring step streams its slot
+straight out of the resident [R·Tr, bm, bk] ring layout instead of a
+copied slice.  Tiles arrive sorted by output tile-row, so each tile-row
+is one consecutive run of grid steps: the f32 VMEM accumulator
+initializes at the run's first tile (from zeros, or from the carried
+ring accumulator in ``acc`` mode) and flushes to the output block at
+the run's last tile.  The layout
 guarantees every tile-row holds at least one (possibly all-zero filler)
 tile, so every output block is written exactly once per (row, s-block).
 
@@ -32,9 +36,10 @@ Both kernels are *partial* (pre-fold) forms mirroring the dense
 fusion (frontier mask / g recompute in VMEM) is identical, the state
 update stays deferred past the psum_scatter fold.  The same entry point
 serves the full-block barrier schedule (operands = the row-gathered
-[R·chunk, s] slice, tiles = the whole block's list) and the
-ring-pipelined schedule (operands = one [chunk, s] chunk, tiles = that
-ring slot's slice, ``acc`` = the running partial carried between hops).
+[R·chunk, s] slice, tiles = the whole block's list, offset 0) and the
+ring-pipelined schedule (operands = one [chunk, s] chunk, tiles = the
+whole ring layout with that ring slot's offset, ``acc`` = the running
+partial carried between hops).
 """
 from __future__ import annotations
 
@@ -59,13 +64,18 @@ __all__ = [
 ]
 
 
-def tiles_to_dense(tiles, tile_rows, tile_cols, m: int, kdim: int) -> jnp.ndarray:
+def tiles_to_dense(
+    tiles, tile_rows, tile_cols, m: int, kdim: int, tile_offset=0
+) -> jnp.ndarray:
     """Reconstruct the dense [m, kdim] block from a tile list (jnp).
 
+    The list's T = len(tile_rows) tiles are
+    ``tiles[tile_offset : tile_offset + T]``, as the kernels read them.
     Reference/debug path only — the kernels never materialize this.
     Filler/padding tiles are all-zero, so scatter-add is exact.
     """
-    t, bm, bk = tiles.shape
+    tiles = jax.lax.dynamic_slice_in_dim(tiles, tile_offset, tile_rows.shape[0], axis=0)
+    _, bm, bk = tiles.shape
     grid = jnp.zeros((m // bm, kdim // bk, bm, bk), jnp.float32)
     grid = grid.at[tile_rows, tile_cols].add(tiles.astype(jnp.float32))
     return grid.transpose(0, 2, 1, 3).reshape(m, kdim)
@@ -109,7 +119,8 @@ def make_sparse_kernel(operand_fn, *, carried: bool):
     are its four products.
 
     Emitted signature (positional refs, matching ``_sparse_call``):
-        rows_ref, cols_ref, lvl_ref   SMEM i32 (scalar prefetch)
+        rows_ref, cols_ref, lvl_ref,  SMEM i32 (scalar prefetch)
+        off_ref
         a_ref                         [1, bm, bk] stored tile
         *operand_refs                 [bk, bs]-tiled operands at tile_cols[t]
         [t_in_ref]                    [bm, bs] ring accumulator (carried)
@@ -117,7 +128,7 @@ def make_sparse_kernel(operand_fn, *, carried: bool):
         acc_ref                       VMEM scratch [bm, bs] f32
     """
 
-    def kernel(rows_ref, cols_ref, lvl_ref, a_ref, *refs, num_tiles: int):
+    def kernel(rows_ref, cols_ref, lvl_ref, off_ref, a_ref, *refs, num_tiles: int):
         acc_ref, t_out_ref = refs[-1], refs[-2]
         t_in_ref = refs[-3] if carried else None
         operand_refs = refs[: -3 if carried else -2]
@@ -156,12 +167,14 @@ def _sparse_call(kernel_pair, m, s, bm, bk, bs, num_tiles, operand_specs, args, 
 
     ``kernel_pair`` = (zero-init, carried-acc) factory products — the
     module-level names above, so the public kernels ARE what runs.
-    ``args`` = (rows, cols, lvl, tiles, *operands); operand tiles index
-    via cols_ref, the output (and ``acc`` input) via rows_ref.
+    ``args`` = (rows, cols, lvl, off, tiles, *operands); the stored tile
+    at grid step t is ``tiles[off + t]``, operand tiles index via
+    cols_ref, the output (and ``acc`` input) via rows_ref.  ``num_tiles``
+    is the length of the row/col id lists, not of ``tiles``.
     """
-    out_spec = pl.BlockSpec((bm, bs), lambda j, t, rows, cols, lvl: (rows[t], j))
+    out_spec = pl.BlockSpec((bm, bs), lambda j, t, rows, cols, *_: (rows[t], j))
     in_specs = [
-        pl.BlockSpec((1, bm, bk), lambda j, t, rows, cols, lvl: (t, 0, 0)),  # tile
+        pl.BlockSpec((1, bm, bk), lambda j, t, rows, cols, lvl, off: (off[0] + t, 0, 0)),
         *operand_specs,
     ]
     kernel = functools.partial(kernel_pair[acc is not None], num_tiles=num_tiles)
@@ -169,7 +182,7 @@ def _sparse_call(kernel_pair, m, s, bm, bk, bs, num_tiles, operand_specs, args, 
         in_specs.append(out_spec)  # t_in rides the output block index
         args = args + (acc,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # rows, cols, lvl
+        num_scalar_prefetch=4,  # rows, cols, lvl, tile offset
         grid=(s // bs, num_tiles),
         in_specs=in_specs,
         out_specs=out_spec,
@@ -184,8 +197,8 @@ def _sparse_call(kernel_pair, m, s, bm, bk, bs, num_tiles, operand_specs, args, 
 
 
 def frontier_sparse_pallas(
-    tiles: jnp.ndarray,  # [T, bm, bk] stored tiles (row-sorted, row-complete)
-    tile_rows: jnp.ndarray,  # i32 [T]
+    tiles: jnp.ndarray,  # [≥ tile_offset + T, bm, bk] stored tiles
+    tile_rows: jnp.ndarray,  # i32 [T] (row-sorted, row-complete)
     tile_cols: jnp.ndarray,  # i32 [T]
     sigma: jnp.ndarray,  # [kdim, s] gathered (or ring-chunk) operand
     depth: jnp.ndarray,  # [kdim, s]
@@ -193,19 +206,21 @@ def frontier_sparse_pallas(
     *,
     m: int,  # output rows (C·chunk)
     acc: jnp.ndarray | None = None,  # [m, s] ring accumulator (chunked mode)
+    tile_offset: jnp.ndarray | int = 0,  # i32: this call's first tile
     bs: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Raw pallas_call; shapes must be tile-aligned (see ops.py)."""
-    num_tiles, bm, bk = tiles.shape
+    (num_tiles,), (bm, bk) = tile_rows.shape, tiles.shape[1:]
     kdim, s = sigma.shape
     assert m % bm == 0 and kdim % bk == 0 and s % bs == 0, (m, kdim, s, bm, bk, bs)
     lvl_arr = jnp.asarray(lvl, jnp.int32).reshape(1)
+    off_arr = jnp.asarray(tile_offset, jnp.int32).reshape(1)
     operand_specs = [
-        pl.BlockSpec((bk, bs), lambda j, t, rows, cols, lvl: (cols[t], j)),  # σ
-        pl.BlockSpec((bk, bs), lambda j, t, rows, cols, lvl: (cols[t], j)),  # d
+        pl.BlockSpec((bk, bs), lambda j, t, rows, cols, *_: (cols[t], j)),  # σ
+        pl.BlockSpec((bk, bs), lambda j, t, rows, cols, *_: (cols[t], j)),  # d
     ]
-    args = (tile_rows, tile_cols, lvl_arr, tiles, sigma, depth)
+    args = (tile_rows, tile_cols, lvl_arr, off_arr, tiles, sigma, depth)
     return _sparse_call(
         (frontier_sparse_kernel, frontier_sparse_acc_kernel),
         m, s, bm, bk, bs, num_tiles, operand_specs, args, acc, interpret,
@@ -213,7 +228,7 @@ def frontier_sparse_pallas(
 
 
 def dependency_sparse_pallas(
-    tiles: jnp.ndarray,  # [T, bm, bk]
+    tiles: jnp.ndarray,  # [≥ tile_offset + T, bm, bk]
     tile_rows: jnp.ndarray,  # i32 [T]
     tile_cols: jnp.ndarray,  # i32 [T]
     sigma: jnp.ndarray,  # [kdim, s]
@@ -224,22 +239,24 @@ def dependency_sparse_pallas(
     *,
     m: int,
     acc: jnp.ndarray | None = None,
+    tile_offset: jnp.ndarray | int = 0,
     bs: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Raw pallas_call; shapes must be tile-aligned (see ops.py)."""
-    num_tiles, bm, bk = tiles.shape
+    (num_tiles,), (bm, bk) = tile_rows.shape, tiles.shape[1:]
     kdim, s = sigma.shape
     assert m % bm == 0 and kdim % bk == 0 and s % bs == 0, (m, kdim, s, bm, bk, bs)
     lvl_arr = jnp.asarray(lvl, jnp.int32).reshape(1)
+    off_arr = jnp.asarray(tile_offset, jnp.int32).reshape(1)
     omega_col = omega.astype(jnp.float32).reshape(kdim, 1)
     operand_specs = [
-        pl.BlockSpec((bk, bs), lambda j, t, rows, cols, lvl: (cols[t], j)),  # σ
-        pl.BlockSpec((bk, bs), lambda j, t, rows, cols, lvl: (cols[t], j)),  # d
-        pl.BlockSpec((bk, bs), lambda j, t, rows, cols, lvl: (cols[t], j)),  # δ
-        pl.BlockSpec((bk, 1), lambda j, t, rows, cols, lvl: (cols[t], 0)),  # ω
+        pl.BlockSpec((bk, bs), lambda j, t, rows, cols, *_: (cols[t], j)),  # σ
+        pl.BlockSpec((bk, bs), lambda j, t, rows, cols, *_: (cols[t], j)),  # d
+        pl.BlockSpec((bk, bs), lambda j, t, rows, cols, *_: (cols[t], j)),  # δ
+        pl.BlockSpec((bk, 1), lambda j, t, rows, cols, *_: (cols[t], 0)),  # ω
     ]
-    args = (tile_rows, tile_cols, lvl_arr, tiles, sigma, depth, delta, omega_col)
+    args = (tile_rows, tile_cols, lvl_arr, off_arr, tiles, sigma, depth, delta, omega_col)
     return _sparse_call(
         (dependency_sparse_kernel, dependency_sparse_acc_kernel),
         m, s, bm, bk, bs, num_tiles, operand_specs, args, acc, interpret,

@@ -312,28 +312,33 @@ def frontier_spmm_sparse(
     *,
     m: int,
     acc=None,
+    tile_offset=0,
     use_pallas: bool = True,
     interpret: bool | None = None,
     bs: int = 128,
 ):
     """Blocked-sparse pre-fold forward partial (BCSR tile list).
 
-    ``tiles`` [T, bm, bk] / ``tile_rows`` / ``tile_cols`` are one
-    device's stored nonzero tiles (row-sorted, row-complete — build with
-    :meth:`repro.graphs.partition.TwoDPartition.blocked_sparse`);
-    ``sigma``/``depth`` are the gathered [kdim, s] operands.  Returns the
-    raw t = A_block @ (σ ⊙ [d = lvl-1]) f32 [m, s], touching only the
-    stored tiles — A-stream bytes O(T · bm · bk) instead of O(m · kdim).
+    ``tile_rows`` / ``tile_cols`` (i32 [T]) index one device's stored
+    nonzero tiles (row-sorted, row-complete — build with
+    :meth:`repro.graphs.partition.TwoDPartition.blocked_sparse`), which
+    are ``tiles[tile_offset : tile_offset + T]`` of the [≥ T, bm, bk]
+    ``tiles``; ``sigma``/``depth`` are the gathered [kdim, s] operands.
+    Returns the raw t = A_block @ (σ ⊙ [d = lvl-1]) f32 [m, s], touching
+    only the stored tiles — A-stream bytes O(T · bm · bk) instead of
+    O(m · kdim).
 
     Modes mirror :func:`frontier_spmm_partial`: full (barrier schedule,
     operands = the whole gathered slice), per-ring-chunk partial
-    (operands = one [chunk, s] chunk, tiles = that ring slot's slice),
-    and chunked-``acc`` (the running ring combine seeds the kernel's
-    VMEM accumulator).  ``m`` is static: the fold-partial row count
+    (operands = one [chunk, s] chunk, tiles = the whole ring layout,
+    ``tile_offset`` = that ring slot's first tile — a traced scalar the
+    kernel's tile index map adds, so no slot is copied), and
+    chunked-``acc`` (the running ring combine seeds the kernel's VMEM
+    accumulator).  ``m`` is static: the fold-partial row count
     (C·chunk), not derivable from the tile list.
     """
     if not use_pallas:
-        a = tiles_to_dense(tiles, tile_rows, tile_cols, m, sigma.shape[0])
+        a = tiles_to_dense(tiles, tile_rows, tile_cols, m, sigma.shape[0], tile_offset)
         t = ref.frontier_partial_ref(a, sigma, depth, lvl)
         return t if acc is None else acc + t
     interpret = resolve_interpret(interpret)
@@ -341,8 +346,8 @@ def frontier_spmm_sparse(
     bs = _pick_block(s, bs, 128)
     sg, dp, ac = _pad_cols(bs, (sigma, 0), (depth, -1), (acc, 0))
     t = frontier_sparse_pallas(
-        tiles, tile_rows, tile_cols, sg, dp, lvl, m=m, acc=ac, bs=bs,
-        interpret=interpret,
+        tiles, tile_rows, tile_cols, sg, dp, lvl, m=m, acc=ac,
+        tile_offset=tile_offset, bs=bs, interpret=interpret,
     )
     return t[:, :s]
 
@@ -360,6 +365,7 @@ def dependency_spmm_sparse(
     *,
     m: int,
     acc=None,
+    tile_offset=0,
     use_pallas: bool = True,
     interpret: bool | None = None,
     bs: int = 128,
@@ -368,11 +374,11 @@ def dependency_spmm_sparse(
 
     Operands are the gathered [kdim, s] (σ, d, δ) and [kdim] ω; the g
     recompute is fused per stored tile.  Returns t = A_block @ g f32
-    [m, s].  Same full / ring-chunk / chunked-``acc`` modes as
-    :func:`frontier_spmm_sparse`.
+    [m, s].  Same full / ring-chunk / chunked-``acc`` modes and
+    ``tile_offset`` as :func:`frontier_spmm_sparse`.
     """
     if not use_pallas:
-        a = tiles_to_dense(tiles, tile_rows, tile_cols, m, sigma.shape[0])
+        a = tiles_to_dense(tiles, tile_rows, tile_cols, m, sigma.shape[0], tile_offset)
         t = ref.dependency_partial_ref(a, sigma, depth, delta, omega, lvl)
         return t if acc is None else acc + t
     interpret = resolve_interpret(interpret)
@@ -380,8 +386,8 @@ def dependency_spmm_sparse(
     bs = _pick_block(s, bs, 128)
     sg, dp, dl, ac = _pad_cols(bs, (sigma, 0), (depth, -1), (delta, 0), (acc, 0))
     t = dependency_sparse_pallas(
-        tiles, tile_rows, tile_cols, sg, dp, dl, omega, lvl, m=m, acc=ac, bs=bs,
-        interpret=interpret,
+        tiles, tile_rows, tile_cols, sg, dp, dl, omega, lvl, m=m, acc=ac,
+        tile_offset=tile_offset, bs=bs, interpret=interpret,
     )
     return t[:, :s]
 

@@ -153,16 +153,14 @@ def test_footprint_prices_ring_layouts():
 @pytest.mark.parametrize("kind", ["sparse", "pallas", "pallas_sparse", "pallas_hybrid"])
 @pytest.mark.parametrize("overlap", ["none", "expand+fold"])
 def test_footprint_prices_ring_slot_copies_of_the_tile_list_only(kind, overlap):
-    """Only the blocked-sparse ring round is known to copy its ring steps'
-    tile slices (``tests/test_tpu_compile.py`` reads the compiled round):
-    the guard adds the ring tile list once more there, and nothing for the
-    other engines or the barrier schedule."""
+    """No engine's ring round copies its ring steps' tile slices any more
+    (the blocked-sparse kernels read their slot in place;
+    ``tests/test_tpu_compile.py`` reads the compiled round): the guard
+    prices the resident adjacency and the state, and no copy."""
     part = partition_2d(rmat_graph(10, 4, seed=1), 2, 2)
     foot = estimate_device_footprint(part, kind, 16, bm=8, bk=8, overlap=overlap)
-    copied = kind == "pallas_sparse" and overlap != "none"
-    assert foot["ring_copy_bytes"] == (foot["adjacency_bytes"] if copied else 0.0)
-    assert foot["total_bytes"] == pytest.approx(
-        foot["adjacency_bytes"] + foot["ring_copy_bytes"] + foot["state_bytes"])
+    assert "ring_copy_bytes" not in foot
+    assert foot["total_bytes"] == foot["adjacency_bytes"] + foot["state_bytes"]
 
 
 # ---------------------------------------------------------------- kernels
@@ -237,6 +235,48 @@ def test_ring_chunk_composition_matches_full(rng):
             interpret=True,
         )
     np.testing.assert_allclose(acc, want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize(
+    "kernel,r,with_acc,use_pallas",
+    [
+        (kernel, r, with_acc, True)
+        for kernel in ("frontier", "dependency")
+        for r in (0, 1)
+        for with_acc in (False, True)
+    ]
+    + [("dependency", 1, True, False)],
+)
+def test_tile_offset_reads_the_ring_slot_in_place(rng, kernel, r, with_acc, use_pallas):
+    """A call on the whole [R·Tr, bm, bk] ring layout at ``tile_offset``
+    r·Tr equals, bit for bit, the call on slot r's own [Tr, bm, bk] tiles."""
+    g = gnp_graph(26, 0.15, seed=0)
+    part, _, ring_lay = _layout(g, 2, 4)
+    chunk, m = part.chunk, 4 * part.chunk
+    i, j = 1, 2
+    ring_tiles = jnp.asarray(ring_lay.ring_tiles[i, j])  # [R, Tr, bm, bk]
+    R, tr, bm, bk = ring_tiles.shape
+    assert R == 2
+    rows = jnp.asarray(ring_lay.ring_tile_rows[i, j, r])
+    cols = jnp.asarray(ring_lay.ring_tile_cols[i, j, r])
+    sigma = jnp.asarray(rng.integers(0, 5, (chunk, S)), jnp.float32)
+    depth = jnp.asarray(rng.integers(-1, 4, (chunk, S)), jnp.int32)
+    delta = jnp.asarray(rng.normal(size=(chunk, S)), jnp.float32)
+    omega = jnp.asarray(rng.integers(0, 3, chunk), jnp.float32)
+    acc = jnp.asarray(rng.normal(size=(m, S)), jnp.float32) if with_acc else None
+    kw = dict(m=m, acc=acc, use_pallas=use_pallas, interpret=True)
+
+    def call(tiles, off):
+        if kernel == "frontier":
+            return ops.frontier_spmm_sparse(
+                tiles, rows, cols, sigma, depth, 2, tile_offset=off, **kw)
+        return ops.dependency_spmm_sparse(
+            tiles, rows, cols, sigma, depth, delta, omega, 2, tile_offset=off, **kw)
+
+    got = call(ring_tiles.reshape(R * tr, bm, bk), r * tr)
+    want = call(ring_tiles[r], 0)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.abs(np.asarray(want)).sum() > 0
 
 
 def test_empty_tiles_are_skipped(rng):
